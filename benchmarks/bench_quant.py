@@ -80,7 +80,7 @@ def _ff_cells():
     emit("quant_ff_fp", t_fp, shape=shape, weight_mb=w_mb,
          bound_us=round(b_fp, 3))
 
-    for qdt in ["int8"] + (["fp8"] if quant.supports_fp8() else []):
+    for qdt in ["int8", "fp8"]:
         _pretune("float8_e4m3fn" if qdt == "fp8" else qdt)
         pq = quant.quantize_params(params, qdt)
         obs.reset_route_counts()

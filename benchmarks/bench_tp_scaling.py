@@ -2,12 +2,13 @@
 psum_scatter, ``kernels.tp``) vs the einsum fallback
 (``REPRO_KERNEL_TP=off`` block-layout ff) at tp = 1 / 2 / 4.
 
-Each tp cell re-execs in a subprocess: the forced host device count is
-locked at first jax init, so a (1, tp) ``("data", "model")`` mesh needs
-its own process.  Inside, both routes run the SAME ``layers.mlp.apply_mlp``
-under the SAME activation-sharding context — the only difference is the
-dispatch ``_ff_kernel_ready`` picks, verified via the ``ff_tp`` route
-counters.
+On CPU each tp cell re-execs in a subprocess: the forced host device
+count is locked at first jax init, so a (1, tp) ``("data", "model")`` mesh
+needs its own process.  On an accelerator the cells run in-process on the
+first tp real devices (a chip belongs to one process).  Both routes run
+the SAME ``layers.mlp.apply_mlp`` under the SAME activation-sharding
+context — the only difference is the dispatch ``_ff_kernel_ready``
+picks, verified via the ``ff_tp`` route counters.
 
 On CPU both routes execute interpret-mode Pallas, so (as everywhere in
 this repo) absolute wall-clock is NOT a TPU number; each record therefore
@@ -36,42 +37,71 @@ N_DYAD = 4
 ACT = "relu"
 TPS = (1, 2, 4)
 
-_CELL = """
-import os, json
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={tp}"
-os.environ["REPRO_KERNEL_FF"] = "fused"
-import jax
-from repro import configs, obs
-from repro.launch.mesh import make_test_mesh
-from repro.layers import mlp
-from repro.sharding import ctx as shard_ctx
-from repro.perf.record import time_us
 
-lin = configs.linear_cfg("dyad_it_4_kernel_ffused")
-params = mlp.init_mlp(jax.random.PRNGKey(0), {d}, {dff}, lin, act="{act}")
-x = jax.random.normal(jax.random.PRNGKey(1), ({tokens}, {d}))
-mesh = make_test_mesh((1, {tp}))
-res = {{}}
-with shard_ctx.activation_sharding(mesh, dp=("data",), model="model"):
-    obs.reset_route_counts()
-    fused = jax.jit(lambda p, x: mlp.apply_mlp(p, x, lin, act="{act}"))
-    res["fused_us"] = time_us(fused, params, x, iters=3, warmup=1)
-    res["routes"] = obs.routes_snapshot()
-    os.environ["REPRO_KERNEL_TP"] = "off"
-    fb = jax.jit(lambda p, x: mlp.apply_mlp(p, x, lin, act="{act}"))
-    res["fallback_us"] = time_us(fb, params, x, iters=3, warmup=1)
-print("CELL" + json.dumps(res))
-"""
+def _measure(tp: int) -> dict:
+    """Time the fused and fallback routes on a (1, tp) mesh over this
+    process's first ``tp`` devices; the ``ff_tp`` route counters of the
+    fused run ride along."""
+    import jax
+
+    from repro import configs, obs
+    from repro.launch.mesh import make_mesh
+    from repro.layers import mlp
+    from repro.perf.record import time_us
+    from repro.sharding import ctx as shard_ctx
+
+    lin = configs.linear_cfg("dyad_it_4_kernel_ffused")
+    params = mlp.init_mlp(jax.random.PRNGKey(0), D, DFF, lin, act=ACT)
+    x = jax.random.normal(jax.random.PRNGKey(1), (TOKENS, D))
+    mesh = make_mesh((1, tp))
+    saved = {k: os.environ.pop(k, None)
+             for k in ("REPRO_KERNEL_FF", "REPRO_KERNEL_TP")}
+    os.environ["REPRO_KERNEL_FF"] = "fused"
+    res = {}
+    try:
+        with shard_ctx.activation_sharding(mesh, dp=("data",),
+                                           model="model"):
+            obs.reset_route_counts()
+            fused = jax.jit(lambda p, x: mlp.apply_mlp(p, x, lin, act=ACT))
+            res["fused_us"] = time_us(fused, params, x, iters=3, warmup=1)
+            res["routes"] = obs.routes_snapshot()
+            os.environ["REPRO_KERNEL_TP"] = "off"
+            fb = jax.jit(lambda p, x: mlp.apply_mlp(p, x, lin, act=ACT))
+            res["fallback_us"] = time_us(fb, params, x, iters=3, warmup=1)
+    finally:
+        for k, v in saved.items():
+            os.environ.pop(k, None)
+            if v is not None:
+                os.environ[k] = v
+    return res
 
 
 def _run_cell(tp: int) -> dict:
+    """One tp cell.  On an accelerator the cells run in THIS process on
+    its real devices: a chip belongs to one process, and this one already
+    holds them.  On CPU each cell needs ``tp`` virtual devices, which the
+    host device count fixes at backend start, so it runs in a child that
+    sets the count before touching a device."""
+    import jax
+
+    if jax.default_backend() != "cpu":
+        have = len(jax.devices())
+        if tp > have:
+            raise RuntimeError(f"tp_scaling cell tp{tp} needs {tp} devices; "
+                               f"this host has {have}")
+        return _measure(tp)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env.pop("REPRO_KERNEL_TP", None)
-    src = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = src + os.pathsep * bool(env.get("PYTHONPATH", "")) \
-        + env.get("PYTHONPATH", "")
-    script = _CELL.format(tp=tp, d=D, dff=DFF, tokens=TOKENS, act=ACT)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(root, "src"), root]
+        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    script = (
+        "import os, json\n"
+        "os.environ['XLA_FLAGS'] = "
+        f"'--xla_force_host_platform_device_count={tp}'\n"
+        "from benchmarks.bench_tp_scaling import _measure\n"
+        f"print('CELL' + json.dumps(_measure({tp})))\n")
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
                        text=True, timeout=570, env=env)
     if r.returncode != 0:

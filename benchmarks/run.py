@@ -51,7 +51,10 @@ sys.path.insert(0, _ROOT)
 
 def main(argv=None) -> int:
     from repro import obs
+    from repro.launch.cache import enable_compile_cache
     from repro.perf import registry
+
+    enable_compile_cache()
 
     # importing the suite modules registers them (repro.perf.register)
     from benchmarks import (bench_attention, bench_ff_fused,  # noqa: F401

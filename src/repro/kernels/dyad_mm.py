@@ -37,8 +37,21 @@ This goes beyond the paper's ``-CAT`` trick: instead of concatenating the two
 components into one ``2*n_dyad``-block bmm (which still materializes the
 concatenated activations), both partial products accumulate in-register/VMEM
 with zero extra HBM traffic.  The feature permutation that defines the
-BLOCKTRANS component is handled by the caller as a strided re-view (``ops.py``)
-so every tile the kernel streams HBM->VMEM is contiguous and 128-aligned.
+BLOCKTRANS component is handled by the caller as a strided re-view
+(``ops.py``).
+
+Layout
+------
+Mosaic accepts a block whose last two dims are multiples of the hardware
+tile (8 sublanes, 128 lanes) or equal to the whole array axis.  The
+layer-natural views are ``(B, n, d)``, where a one-dyad-block tile
+``(bB, 1, bK)`` breaks that rule on its middle axis.  Every kernel here
+therefore streams activations BLOCK-MAJOR, ``(n, B, d)``: the dyad-block
+axis leads and is squeezed out of each block, leaving ``(bB, bK)`` tiles.
+The public wrappers keep the ``(B, n, d)`` interface and swap the two
+leading axes on the way in and out — one XLA transpose per activation
+operand and per result, the price of this layout.  Per-row quantization
+scales ride as ``(n, 1, d_out)`` so their tiles are ``(1, bO)``.
 
 Grid: ``(n_dyad, B/bB, d_out/bO, d_in/bK)`` — the k axis is innermost so the
 accumulator tile is revisited on consecutive steps; block=g, batch and out
@@ -49,10 +62,10 @@ Tile selection
 ``block_b/block_o/block_k`` default to the autotuned sizes for this
 ``(shape, dtype, backend)`` key (:func:`repro.perf.autotune.get_tuned_blocks`;
 falls back to 256/256/512 when the shape was never tuned).  Tiles are then
-*planned* per axis: a dimension whose largest divisor under the requested
-block is degenerate (prime or odd dims used to collapse to 1-wide tiles and
-a catastrophic grid) is zero-padded up to a tile-unit multiple instead —
-zero rows/columns contribute nothing and are sliced off the output.
+*planned* per axis (:func:`_plan_axis`): an axis that fits the requested
+block is one whole-axis tile; a longer one is tiled by a multiple of the
+hardware tile, zero-padded up to one when needed — zero rows/columns
+contribute nothing and are sliced off the output.
 """
 from __future__ import annotations
 
@@ -64,14 +77,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+# ONE activation table for kernel epilogue and oracle — keep them in sync
+from repro.kernels.ref import ACTS as _FF_ACTS
 
 # minimal healthy tile per axis: sublane granularity on the batch axis,
 # lane granularity on the feature axes (fp32 native tile is (8, 128))
 _UNIT_B = 8
 _UNIT_FEAT = 128
+
+# the leading dyad-block axis: one block per grid step, squeezed out of
+# every tile so the kernel body sees plain 2-D (rows, lanes) refs
+_SQ = pl.Squeezed()
 
 
 def _largest_divisor(dim: int, target: int) -> int:
@@ -84,16 +100,20 @@ def _largest_divisor(dim: int, target: int) -> int:
 def _plan_axis(dim: int, block: int, unit: int):
     """(tile, padded_dim) for one grid axis.
 
-    Healthy case: the largest divisor of ``dim`` under ``block`` is at least
-    one tile unit (or the whole axis) — use it, no padding.  Degenerate case
-    (prime/odd dims whose best divisor is tiny): round the axis up to a
-    multiple of the unit so a real tile exists; the caller zero-pads."""
-    u = max(min(unit, block), 1)
-    d = _largest_divisor(dim, block)
-    if d >= min(u, dim):
-        return d, dim
-    padded = -(-dim // u) * u
-    return _largest_divisor(padded, block), padded
+    Every tile is legal for Mosaic: the whole axis, or a multiple of
+    ``unit`` (8 on a sublane axis, 128 on a lane axis).  An axis no longer
+    than the requested block (or the unit) is one whole-axis tile.  A
+    longer axis is rounded up to a multiple of ``unit`` (a no-op when it
+    already is one — the caller zero-pads otherwise) and tiled by the
+    largest multiple of ``unit`` within the block that divides it."""
+    block = max(block, unit)
+    if dim <= block:
+        return dim, dim
+    padded = -(-dim // unit) * unit
+    tile = block // unit * unit
+    while padded % tile:
+        tile -= unit
+    return tile, padded
 
 
 @dataclasses.dataclass(frozen=True)
@@ -137,150 +157,131 @@ def resolve_blocks(op: str, B: int, n: int, d_in: int, d_out: int, dtype,
     return block_b, block_o, block_k
 
 
-def _pad_inputs(plan: TilePlan, x1, x2, w1, w2):
-    B, _, d_in = x1.shape
-    _, d_out, _ = w1.shape
-    db, do, dk = (plan.padded_b - B, plan.padded_o - d_out,
-                  plan.padded_k - d_in)
-    if db or dk:
-        x1 = jnp.pad(x1, ((0, db), (0, 0), (0, dk)))
-        x2 = jnp.pad(x2, ((0, db), (0, 0), (0, dk)))
-    if do or dk:
-        w1 = jnp.pad(w1, ((0, 0), (0, do), (0, dk)))
-        w2 = jnp.pad(w2, ((0, 0), (0, do), (0, dk)))
-    return x1, x2, w1, w2
+def _block_major(x):
+    """``(B, n, d)`` layer view <-> ``(n, B, d)`` kernel layout (the swap
+    is its own inverse)."""
+    return jnp.swapaxes(x, 0, 1)
 
 
-def _dyad_kernel(x1_ref, x2_ref, w1_ref, w2_ref, o_ref, acc_ref, *, nk: int):
+def _pad(x, widths):
+    """Zero-pad the high end of each axis by ``widths`` (no-op if all 0)."""
+    if not any(widths):
+        return x
+    return jnp.pad(x, [(0, w) for w in widths])
+
+
+def _compiler_params(n_parallel: int, n_arbitrary: int):
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * n_parallel
+        + ("arbitrary",) * n_arbitrary)
+
+
+# -- forward and dgrad: one body ----------------------------------------------
+#
+# Both contract a block-major activation tile ``(bB, bK)`` against a weight
+# tile and accumulate in fp32 over the innermost grid axis.  The forward
+# contracts the weight tile's lanes (``(bO, bK)``: x·wᵀ); dgrad contracts
+# its sublanes (``(bK, bO)`` over the SAME ``(n, d_out, d_in)`` weight: the
+# transposed-block product without ever transposing the weight).  For the
+# dgrad grid ``(n, B/bB, d_in/bI, d_out/bK)`` the tile roles keep the
+# layer-natural names in the autotune ``blocks`` dict: ``block_o`` tiles
+# the produced feature axis (d_in there), ``block_k`` the contracted one.
+
+_FWD_DN = (((1,), (1,)), ((), ()))      # (bB, bK) x (bO, bK) -> (bB, bO)
+_DGRAD_DN = (((1,), (0,)), ((), ()))    # (bB, bK) x (bK, bO) -> (bB, bO)
+
+
+def _mm_kernel(*refs, nk: int, two: bool, quant: bool, dn):
+    """acc += x1·w1 (*s1) + x2·w2 (*s2) over grid axis 3.
+
+    ``two`` keeps the components in separate accumulators and outputs (the
+    OT/DT forward, whose components write different output layouts, and
+    the IT/DT dgrad, whose components un-view differently); otherwise both
+    partial products land in ONE accumulator.  ``quant`` streams int8/fp8
+    weight tiles, cast to the activation dtype in-register, and multiplies
+    the per-(block, out_row) fp32 scale into each partial product."""
+    x1, x2, w1, w2 = refs[:4]
+    scales = refs[4:6] if quant else (None, None)
+    rest = refs[6 if quant else 4:]
+    n_acc = 2 if two else 1
+    outs, accs = rest[:n_acc], rest[n_acc:]
     k = pl.program_id(3)
 
     @pl.when(k == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        for acc in accs:
+            acc[...] = jnp.zeros_like(acc)
 
-    # (bB, bK) x (bO, bK)^T -> (bB, bO), accumulated in fp32 on the MXU.
-    dn = (((1,), (1,)), ((), ()))
-    acc_ref[...] += jax.lax.dot_general(
-        x1_ref[:, 0, :], w1_ref[0], dn, preferred_element_type=jnp.float32
-    )
-    acc_ref[...] += jax.lax.dot_general(
-        x2_ref[:, 0, :], w2_ref[0], dn, preferred_element_type=jnp.float32
-    )
+    for x, w, s, acc in zip((x1, x2), (w1, w2), scales, (accs[0], accs[-1])):
+        part = jax.lax.dot_general(x[...], w[...].astype(x.dtype), dn,
+                                   preferred_element_type=jnp.float32)
+        acc[...] += part if s is None else part * s[...]
 
     @pl.when(k == nk - 1)
     def _flush():
-        o_ref[:, 0, :] = acc_ref[...].astype(o_ref.dtype)
-
-
-def _dyad_kernel_two(x1_ref, x2_ref, w1_ref, w2_ref, o1_ref, o2_ref,
-                     acc1_ref, acc2_ref, *, nk: int):
-    """Two-accumulator body for OT/DT, whose components write to different
-    output layouts (BLOCKDIAG contiguous vs BLOCKTRANS strided): the kernel
-    emits both per-block products; the caller applies the output re-view."""
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
-    def _init():
-        acc1_ref[...] = jnp.zeros_like(acc1_ref)
-        acc2_ref[...] = jnp.zeros_like(acc2_ref)
-
-    dn = (((1,), (1,)), ((), ()))
-    acc1_ref[...] += jax.lax.dot_general(
-        x1_ref[:, 0, :], w1_ref[0], dn, preferred_element_type=jnp.float32
-    )
-    acc2_ref[...] += jax.lax.dot_general(
-        x2_ref[:, 0, :], w2_ref[0], dn, preferred_element_type=jnp.float32
-    )
-
-    @pl.when(k == nk - 1)
-    def _flush():
-        o1_ref[:, 0, :] = acc1_ref[...].astype(o1_ref.dtype)
-        o2_ref[:, 0, :] = acc2_ref[...].astype(o2_ref.dtype)
+        for out, acc in zip(outs, accs):
+            out[...] = acc[...].astype(out.dtype)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("bB", "bO", "bK", "interpret")
+    jax.jit, static_argnames=("bB", "bO", "bK", "two", "dgrad", "interpret")
 )
-def _dyad_mm_two_impl(x1, x2, w1, w2, *, bB: int, bO: int, bK: int,
-                      interpret: bool):
-    B, n, d_in = x1.shape
-    _, d_out, _ = w1.shape
-    nk = d_in // bK
-    grid = (n, B // bB, d_out // bO, nk)
+def _mm_impl(x1, x2, w1, w2, scales, *, bB: int, bO: int, bK: int,
+             two: bool, dgrad: bool, interpret: bool):
+    """x1, x2: block-major (n, B, K).  w1, w2: (n, O, K), or (n, K, O) for
+    dgrad.  scales: () or (s1, s2) as (n, 1, O).  Returns a tuple of one
+    or (``two``) two (n, B, O) outputs in x1's dtype."""
+    n, B, K = x1.shape
+    O = w1.shape[2] if dgrad else w1.shape[1]
+    nk = K // bK
 
-    x_spec = pl.BlockSpec((bB, 1, bK), lambda g, b, o, k: (b, g, k))
-    w_spec = pl.BlockSpec((1, bO, bK), lambda g, b, o, k: (g, o, k))
-    o_spec = pl.BlockSpec((bB, 1, bO), lambda g, b, o, k: (b, g, o))
-    out_sds = jax.ShapeDtypeStruct((B, n, d_out), x1.dtype)
+    x_spec = pl.BlockSpec((_SQ, bB, bK), lambda g, b, o, k: (g, b, k))
+    if dgrad:
+        w_spec = pl.BlockSpec((_SQ, bK, bO), lambda g, b, o, k: (g, k, o))
+    else:
+        w_spec = pl.BlockSpec((_SQ, bO, bK), lambda g, b, o, k: (g, o, k))
+    s_spec = pl.BlockSpec((_SQ, 1, bO), lambda g, b, o, k: (g, 0, o))
+    o_spec = pl.BlockSpec((_SQ, bB, bO), lambda g, b, o, k: (g, b, o))
+    n_acc = 2 if two else 1
+    out_sds = jax.ShapeDtypeStruct((n, B, O), x1.dtype)
+    body = functools.partial(_mm_kernel, nk=nk, two=two, quant=bool(scales),
+                             dn=_DGRAD_DN if dgrad else _FWD_DN)
 
-    return pl.pallas_call(
-        functools.partial(_dyad_kernel_two, nk=nk),
-        grid=grid,
-        in_specs=[x_spec, x_spec, w_spec, w_spec],
-        out_specs=[o_spec, o_spec],
-        out_shape=[out_sds, out_sds],
-        scratch_shapes=[
-            pltpu.VMEM((bB, bO), jnp.float32),
-            pltpu.VMEM((bB, bO), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        ),
+    outs = pl.pallas_call(
+        body,
+        grid=(n, B // bB, O // bO, nk),
+        in_specs=[x_spec, x_spec, w_spec, w_spec] + [s_spec] * len(scales),
+        out_specs=[o_spec] * n_acc,
+        out_shape=[out_sds] * n_acc,
+        scratch_shapes=[pltpu.VMEM((bB, bO), jnp.float32)] * n_acc,
+        compiler_params=_compiler_params(3, 1),
         interpret=interpret,
-    )(x1, x2, w1, w2)
+    )(x1, x2, w1, w2, *scales)
+    return tuple(outs)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("bB", "bO", "bK", "interpret")
-)
-def _dyad_mm_impl(x1, x2, w1, w2, *, bB: int, bO: int, bK: int,
-                  interpret: bool):
-    B, n, d_in = x1.shape
-    _, d_out, _ = w1.shape
-    nk = d_in // bK
-    grid = (n, B // bB, d_out // bO, nk)
+def _run_mm(op: str, x1, x2, w1, w2, scales, blocks, *, two: bool,
+            dgrad: bool, key_dtype, interpret: bool):
+    """Resolve + plan tiles, pad, go block-major, run, come back.
 
-    x_spec = pl.BlockSpec((bB, 1, bK), lambda g, b, o, k: (b, g, k))
-    w_spec = pl.BlockSpec((1, bO, bK), lambda g, b, o, k: (g, o, k))
-    o_spec = pl.BlockSpec((bB, 1, bO), lambda g, b, o, k: (b, g, o))
-
-    return pl.pallas_call(
-        functools.partial(_dyad_kernel, nk=nk),
-        grid=grid,
-        in_specs=[x_spec, x_spec, w_spec, w_spec],
-        out_specs=o_spec,
-        out_shape=jax.ShapeDtypeStruct((B, n, d_out), x1.dtype),
-        scratch_shapes=[pltpu.VMEM((bB, bO), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(x1, x2, w1, w2)
-
-
-def dyad_mm_blocks_two(
-    x1: jax.Array,
-    x2: jax.Array,
-    w1: jax.Array,
-    w2: jax.Array,
-    *,
-    block_b: int = None,
-    block_o: int = None,
-    block_k: int = None,
-    interpret: bool = False,
-):
-    """As :func:`dyad_mm_blocks` but returns (z1, z2) separately (OT/DT)."""
-    B, n, d_in = x1.shape
-    _, d_out, _ = w1.shape
-    bb, bo, bk = resolve_blocks("dyad_mm_blocks_two", B, n, d_in, d_out,
-                                x1.dtype, block_b, block_o, block_k)
-    plan = plan_tiles(B, d_out, d_in, bb, bo, bk)
-    x1, x2, w1, w2 = _pad_inputs(plan, x1, x2, w1, w2)
-    z1, z2 = _dyad_mm_two_impl(x1, x2, w1, w2, bB=plan.bB, bO=plan.bO,
-                               bK=plan.bK, interpret=interpret)
-    if plan.padded_b != B or plan.padded_o != d_out:
-        z1, z2 = z1[:B, :, :d_out], z2[:B, :, :d_out]
-    return z1, z2
+    x1, x2: (B, n, K) layer views; w1, w2 as :func:`_mm_impl`; scales: ()
+    or (s1, s2) as (n, O).  ``key_dtype`` is the dtype field of the
+    autotune key.  Returns (B, n, O), or a pair of them when ``two``."""
+    B, n, K = x1.shape
+    O = w1.shape[2] if dgrad else w1.shape[1]
+    d_in, d_out = (O, K) if dgrad else (K, O)       # layer-natural key dims
+    bb, bo, bk = resolve_blocks(op, B, n, d_in, d_out, key_dtype, *blocks)
+    plan = plan_tiles(B, O, K, bb, bo, bk)
+    db, do, dk = (plan.padded_b - B, plan.padded_o - O, plan.padded_k - K)
+    x1, x2 = (_pad(_block_major(x), (0, db, dk)) for x in (x1, x2))
+    w1, w2 = (_pad(w, (0, dk, do) if dgrad else (0, do, dk))
+              for w in (w1, w2))
+    # padded out rows hold zero weights; their scale value is moot
+    scales = tuple(_pad(s[:, None, :], (0, 0, do)) for s in scales)
+    outs = _mm_impl(x1, x2, w1, w2, scales, bB=plan.bB, bO=plan.bO,
+                    bK=plan.bK, two=two, dgrad=dgrad, interpret=interpret)
+    outs = tuple(_block_major(z[:, :B, :O]) for z in outs)
+    return outs if two else outs[0]
 
 
 def dyad_mm_blocks(
@@ -303,141 +304,26 @@ def dyad_mm_blocks(
     Block sizes default to the autotuned tiles for this shape/dtype/backend
     (``repro.perf.autotune``); pass explicit values to override.
     """
-    B, n, d_in = x1.shape
-    _, d_out, _ = w1.shape
-    bb, bo, bk = resolve_blocks("dyad_mm_blocks", B, n, d_in, d_out,
-                                x1.dtype, block_b, block_o, block_k)
-    plan = plan_tiles(B, d_out, d_in, bb, bo, bk)
-    x1, x2, w1, w2 = _pad_inputs(plan, x1, x2, w1, w2)
-    out = _dyad_mm_impl(x1, x2, w1, w2, bB=plan.bB, bO=plan.bO, bK=plan.bK,
-                        interpret=interpret)
-    if plan.padded_b != B or plan.padded_o != d_out:
-        out = out[:B, :, :d_out]
-    return out
+    return _run_mm("dyad_mm_blocks", x1, x2, w1, w2, (),
+                   (block_b, block_o, block_k), two=False, dgrad=False,
+                   key_dtype=x1.dtype, interpret=interpret)
 
 
-# -- backward: dgrad (input cotangent) ----------------------------------------
-#
-# Grid ``(n, B/bB, d_in/bI, d_out/bK)`` — the reduction now runs over the
-# OUTPUT feature axis ``o``, innermost so the dx accumulator tile is revisited
-# on consecutive steps.  Tile roles for the autotune ``blocks`` dict keep the
-# layer-natural names: ``block_o`` tiles the produced feature axis (d_in here),
-# ``block_k`` tiles the contracted one (d_out here).
-
-
-def _dgrad_kernel(z1_ref, z2_ref, w1_ref, w2_ref, o_ref, acc_ref, *, nk: int):
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # (bB, bK) x (bK, bI) -> (bB, bI): contract z's o axis with w's o axis —
-    # the transposed-block product without ever transposing the weight tile.
-    dn = (((1,), (0,)), ((), ()))
-    acc_ref[...] += jax.lax.dot_general(
-        z1_ref[:, 0, :], w1_ref[0], dn, preferred_element_type=jnp.float32
-    )
-    acc_ref[...] += jax.lax.dot_general(
-        z2_ref[:, 0, :], w2_ref[0], dn, preferred_element_type=jnp.float32
-    )
-
-    @pl.when(k == nk - 1)
-    def _flush():
-        o_ref[:, 0, :] = acc_ref[...].astype(o_ref.dtype)
-
-
-def _dgrad_kernel_two(z1_ref, z2_ref, w1_ref, w2_ref, o1_ref, o2_ref,
-                      acc1_ref, acc2_ref, *, nk: int):
-    """Two-accumulator dgrad for variants whose per-component input views
-    live in different layouts (IT/DT: component 2's dx must be un-permuted
-    before the add, which is a re-view the caller applies)."""
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
-    def _init():
-        acc1_ref[...] = jnp.zeros_like(acc1_ref)
-        acc2_ref[...] = jnp.zeros_like(acc2_ref)
-
-    dn = (((1,), (0,)), ((), ()))
-    acc1_ref[...] += jax.lax.dot_general(
-        z1_ref[:, 0, :], w1_ref[0], dn, preferred_element_type=jnp.float32
-    )
-    acc2_ref[...] += jax.lax.dot_general(
-        z2_ref[:, 0, :], w2_ref[0], dn, preferred_element_type=jnp.float32
-    )
-
-    @pl.when(k == nk - 1)
-    def _flush():
-        o1_ref[:, 0, :] = acc1_ref[...].astype(o1_ref.dtype)
-        o2_ref[:, 0, :] = acc2_ref[...].astype(o2_ref.dtype)
-
-
-def _dgrad_specs(bB: int, bI: int, bK: int):
-    z_spec = pl.BlockSpec((bB, 1, bK), lambda g, b, i, k: (b, g, k))
-    w_spec = pl.BlockSpec((1, bK, bI), lambda g, b, i, k: (g, k, i))
-    o_spec = pl.BlockSpec((bB, 1, bI), lambda g, b, i, k: (b, g, i))
-    return z_spec, w_spec, o_spec
-
-
-@functools.partial(
-    jax.jit, static_argnames=("bB", "bI", "bK", "fused", "interpret")
-)
-def _dgrad_impl(z1, z2, w1, w2, *, bB: int, bI: int, bK: int, fused: bool,
-                interpret: bool):
-    B, n, d_out = z1.shape
-    _, _, d_in = w1.shape
-    nk = d_out // bK
-    grid = (n, B // bB, d_in // bI, nk)
-    z_spec, w_spec, o_spec = _dgrad_specs(bB, bI, bK)
-    out_sds = jax.ShapeDtypeStruct((B, n, d_in), z1.dtype)
-    acc = pltpu.VMEM((bB, bI), jnp.float32)
-
-    if fused:
-        return pl.pallas_call(
-            functools.partial(_dgrad_kernel, nk=nk),
-            grid=grid,
-            in_specs=[z_spec, z_spec, w_spec, w_spec],
-            out_specs=o_spec,
-            out_shape=out_sds,
-            scratch_shapes=[acc],
-            compiler_params=_CompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel",
-                                     "arbitrary"),
-            ),
-            interpret=interpret,
-        )(z1, z2, w1, w2)
-    return pl.pallas_call(
-        functools.partial(_dgrad_kernel_two, nk=nk),
-        grid=grid,
-        in_specs=[z_spec, z_spec, w_spec, w_spec],
-        out_specs=[o_spec, o_spec],
-        out_shape=[out_sds, out_sds],
-        scratch_shapes=[acc, acc],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-        ),
-        interpret=interpret,
-    )(z1, z2, w1, w2)
-
-
-def _dgrad_prepare(op: str, z1, z2, w1, w2, block_b, block_o, block_k):
-    B, n, d_out = z1.shape
-    _, _, d_in = w1.shape
-    bb, bo, bk = resolve_blocks(op, B, n, d_in, d_out, z1.dtype,
-                                block_b, block_o, block_k)
-    # produced axis = d_in (tiled by block_o), contracted axis = d_out
-    plan = plan_tiles(B, d_in, d_out, bb, bo, bk)
-    db, di, dk = (plan.padded_b - B, plan.padded_o - d_in,
-                  plan.padded_k - d_out)
-    if db or dk:
-        z1 = jnp.pad(z1, ((0, db), (0, 0), (0, dk)))
-        z2 = jnp.pad(z2, ((0, db), (0, 0), (0, dk)))
-    if di or dk:
-        w1 = jnp.pad(w1, ((0, 0), (0, dk), (0, di)))
-        w2 = jnp.pad(w2, ((0, 0), (0, dk), (0, di)))
-    return z1, z2, w1, w2, plan
+def dyad_mm_blocks_two(
+    x1: jax.Array,
+    x2: jax.Array,
+    w1: jax.Array,
+    w2: jax.Array,
+    *,
+    block_b: int = None,
+    block_o: int = None,
+    block_k: int = None,
+    interpret: bool = False,
+):
+    """As :func:`dyad_mm_blocks` but returns (z1, z2) separately (OT/DT)."""
+    return _run_mm("dyad_mm_blocks_two", x1, x2, w1, w2, (),
+                   (block_b, block_o, block_k), two=True, dgrad=False,
+                   key_dtype=x1.dtype, interpret=interpret)
 
 
 def dyad_mm_dgrad(
@@ -458,15 +344,9 @@ def dyad_mm_dgrad(
     Returns dx (B, n_dyad, d_in), dtype of z1.  Valid whenever both dx
     components share a layout (the OT variant's input side).
     """
-    B, _, _ = z1.shape
-    _, _, d_in = w1.shape
-    z1, z2, w1, w2, plan = _dgrad_prepare("dyad_mm_dgrad", z1, z2, w1, w2,
-                                          block_b, block_o, block_k)
-    dx = _dgrad_impl(z1, z2, w1, w2, bB=plan.bB, bI=plan.bO, bK=plan.bK,
-                     fused=True, interpret=interpret)
-    if plan.padded_b != B or plan.padded_o != d_in:
-        dx = dx[:B, :, :d_in]
-    return dx
+    return _run_mm("dyad_mm_dgrad", z1, z2, w1, w2, (),
+                   (block_b, block_o, block_k), two=False, dgrad=True,
+                   key_dtype=z1.dtype, interpret=interpret)
 
 
 def dyad_mm_dgrad_two(
@@ -481,15 +361,9 @@ def dyad_mm_dgrad_two(
     interpret: bool = False,
 ):
     """As :func:`dyad_mm_dgrad` but returns (dx1, dx2) separately (IT/DT)."""
-    B, _, _ = z1.shape
-    _, _, d_in = w1.shape
-    z1, z2, w1, w2, plan = _dgrad_prepare("dyad_mm_dgrad_two", z1, z2, w1, w2,
-                                          block_b, block_o, block_k)
-    dx1, dx2 = _dgrad_impl(z1, z2, w1, w2, bB=plan.bB, bI=plan.bO,
-                           bK=plan.bK, fused=False, interpret=interpret)
-    if plan.padded_b != B or plan.padded_o != d_in:
-        dx1, dx2 = dx1[:B, :, :d_in], dx2[:B, :, :d_in]
-    return dx1, dx2
+    return _run_mm("dyad_mm_dgrad_two", z1, z2, w1, w2, (),
+                   (block_b, block_o, block_k), two=True, dgrad=True,
+                   key_dtype=z1.dtype, interpret=interpret)
 
 
 # -- backward: wgrad (weight cotangents) --------------------------------------
@@ -512,18 +386,14 @@ def _wgrad_kernel(x1_ref, x2_ref, z1_ref, z2_ref, o1_ref, o2_ref,
     # (bB, bO)^T x (bB, bI) -> (bO, bI): contract the batch axes.
     dn = (((0,), (0,)), ((), ()))
     acc1_ref[...] += jax.lax.dot_general(
-        z1_ref[:, 0, :], x1_ref[:, 0, :], dn,
-        preferred_element_type=jnp.float32
-    )
+        z1_ref[...], x1_ref[...], dn, preferred_element_type=jnp.float32)
     acc2_ref[...] += jax.lax.dot_general(
-        z2_ref[:, 0, :], x2_ref[:, 0, :], dn,
-        preferred_element_type=jnp.float32
-    )
+        z2_ref[...], x2_ref[...], dn, preferred_element_type=jnp.float32)
 
     @pl.when(b == nb - 1)
     def _flush():
-        o1_ref[0, :, :] = acc1_ref[...].astype(o1_ref.dtype)
-        o2_ref[0, :, :] = acc2_ref[...].astype(o2_ref.dtype)
+        o1_ref[...] = acc1_ref[...].astype(o1_ref.dtype)
+        o2_ref[...] = acc2_ref[...].astype(o2_ref.dtype)
 
 
 @functools.partial(
@@ -531,28 +401,25 @@ def _wgrad_kernel(x1_ref, x2_ref, z1_ref, z2_ref, o1_ref, o2_ref,
 )
 def _wgrad_impl(x1, x2, z1, z2, *, bB: int, bO: int, bI: int,
                 out_dtype: str, interpret: bool):
-    B, n, d_in = x1.shape
-    _, _, d_out = z1.shape
+    """x1, x2: block-major (n, B, d_in); z1, z2: (n, B, d_out)."""
+    n, B, d_in = x1.shape
+    d_out = z1.shape[2]
     nb = B // bB
-    grid = (n, d_out // bO, d_in // bI, nb)
 
-    x_spec = pl.BlockSpec((bB, 1, bI), lambda g, o, i, b: (b, g, i))
-    z_spec = pl.BlockSpec((bB, 1, bO), lambda g, o, i, b: (b, g, o))
-    o_spec = pl.BlockSpec((1, bO, bI), lambda g, o, i, b: (g, o, i))
+    x_spec = pl.BlockSpec((_SQ, bB, bI), lambda g, o, i, b: (g, b, i))
+    z_spec = pl.BlockSpec((_SQ, bB, bO), lambda g, o, i, b: (g, b, o))
+    o_spec = pl.BlockSpec((_SQ, bO, bI), lambda g, o, i, b: (g, o, i))
     out_sds = jax.ShapeDtypeStruct((n, d_out, d_in), jnp.dtype(out_dtype))
     acc = pltpu.VMEM((bO, bI), jnp.float32)
 
     return pl.pallas_call(
         functools.partial(_wgrad_kernel, nb=nb),
-        grid=grid,
+        grid=(n, d_out // bO, d_in // bI, nb),
         in_specs=[x_spec, x_spec, z_spec, z_spec],
         out_specs=[o_spec, o_spec],
         out_shape=[out_sds, out_sds],
         scratch_shapes=[acc, acc],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-        ),
+        compiler_params=_compiler_params(3, 1),
         interpret=interpret,
     )(x1, x2, z1, z2)
 
@@ -584,18 +451,12 @@ def dyad_mm_wgrad(
     plan = plan_tiles(B, d_out, d_in, bb, bo, bk)
     db, do, di = (plan.padded_b - B, plan.padded_o - d_out,
                   plan.padded_k - d_in)
-    if db or di:
-        x1 = jnp.pad(x1, ((0, db), (0, 0), (0, di)))
-        x2 = jnp.pad(x2, ((0, db), (0, 0), (0, di)))
-    if db or do:
-        z1 = jnp.pad(z1, ((0, db), (0, 0), (0, do)))
-        z2 = jnp.pad(z2, ((0, db), (0, 0), (0, do)))
+    x1, x2 = (_pad(_block_major(x), (0, db, di)) for x in (x1, x2))
+    z1, z2 = (_pad(_block_major(z), (0, db, do)) for z in (z1, z2))
     dw1, dw2 = _wgrad_impl(x1, x2, z1, z2, bB=plan.bB, bO=plan.bO,
                            bI=plan.bK, out_dtype=str(out_dtype),
                            interpret=interpret)
-    if plan.padded_o != d_out or plan.padded_k != d_in:
-        dw1, dw2 = dw1[:, :d_out, :d_in], dw2[:, :d_out, :d_in]
-    return dw1, dw2
+    return dw1[:, :d_out, :d_in], dw2[:, :d_out, :d_in]
 
 
 # -- megakernel: the whole ff module in one grid ------------------------------
@@ -618,9 +479,6 @@ def dyad_mm_wgrad(
 # The o axis revisits recompute the hidden once per output tile; for DYAD ff
 # dims the per-block down output d_model/n fits one tile (d_out/bO == 1), so
 # in practice the hidden is computed exactly once.
-
-# ONE activation table for kernel epilogue and oracle — keep them in sync
-from repro.kernels.ref import ACTS as _FF_ACTS  # noqa: E402
 
 
 @dataclasses.dataclass(frozen=True)
@@ -677,136 +535,140 @@ def resolve_ff_blocks(op: str, B: int, n: int, d_in: int, d_out: int,
     return block_b, block_o, block_k, block_j
 
 
-def _ff_kernel(x1_ref, x2_ref, wu1_ref, wu2_ref, wd1_ref, wd2_ref,
-               z1_ref, z2_ref, hacc_ref, acc1_ref, acc2_ref, *,
-               nj: int, nk: int, act: str):
+def _ff_kernel(*refs, nj: int, nk: int, act: str, quant: bool):
+    """Operands: x1, x2, the up weights ((wg1, wg2,) wu1, wu2), wd1, wd2,
+    [their scales in the same order when ``quant``], z1, z2, then scratch:
+    one hidden accumulator per up projection (gate first), acc1, acc2.
+    SwiGLU runs TWO up accumulators over the shared k loop; the gated
+    product forms in-register at the k flush."""
+    n_up = 4 if act == "swiglu" else 2
+    x1, x2 = refs[:2]
+    ups = refs[2:2 + n_up]
+    wd1, wd2 = refs[2 + n_up:4 + n_up]
+    i = 4 + n_up
+    if quant:
+        s_ups, (sd1, sd2) = refs[i:i + n_up], refs[i + n_up:i + n_up + 2]
+        i += n_up + 2
+    else:
+        s_ups, sd1, sd2 = (None,) * n_up, None, None
+    z1, z2 = refs[i:i + 2]
+    haccs = refs[i + 2:-2]
+    acc1, acc2 = refs[-2:]
     j = pl.program_id(3)
     k = pl.program_id(4)
 
     @pl.when(jnp.logical_and(j == 0, k == 0))
     def _init_down():
-        acc1_ref[...] = jnp.zeros_like(acc1_ref)
-        acc2_ref[...] = jnp.zeros_like(acc2_ref)
+        acc1[...] = jnp.zeros_like(acc1)
+        acc2[...] = jnp.zeros_like(acc2)
 
     @pl.when(k == 0)
     def _init_up():
-        hacc_ref[...] = jnp.zeros_like(hacc_ref)
+        for h in haccs:
+            h[...] = jnp.zeros_like(h)
 
-    # up: (bB, bK) x (bJ, bK)^T -> (bB, bJ), fp32 on the MXU.
     dn = (((1,), (1,)), ((), ()))
-    hacc_ref[...] += jax.lax.dot_general(
-        x1_ref[:, 0, :], wu1_ref[0], dn, preferred_element_type=jnp.float32
-    )
-    hacc_ref[...] += jax.lax.dot_general(
-        x2_ref[:, 0, :], wu2_ref[0], dn, preferred_element_type=jnp.float32
-    )
+
+    def dot(a, w, s):
+        # (rows, c) x (out, c)^T -> (rows, out), fp32 on the MXU; a
+        # quantized weight tile is cast in-register and its per-row scale
+        # applied to the partial product (exact: the scale is c-invariant)
+        part = jax.lax.dot_general(a, w[...].astype(a.dtype), dn,
+                                   preferred_element_type=jnp.float32)
+        return part if s is None else part * s[...]
+
+    for h, p in zip(haccs, range(0, n_up, 2)):
+        h[...] += dot(x1[...], ups[p], s_ups[p])
+        h[...] += dot(x2[...], ups[p + 1], s_ups[p + 1])
 
     @pl.when(k == nk - 1)
     def _act_and_down():
         # activation epilogue in-register, then the down dot consumes the
         # hidden tile without it ever leaving VMEM.
-        h = _FF_ACTS[act](hacc_ref[...]).astype(x1_ref.dtype)
-        acc1_ref[...] += jax.lax.dot_general(
-            h, wd1_ref[0], dn, preferred_element_type=jnp.float32
-        )
-        acc2_ref[...] += jax.lax.dot_general(
-            h, wd2_ref[0], dn, preferred_element_type=jnp.float32
-        )
+        if act == "swiglu":
+            hv = jax.nn.silu(haccs[0][...]) * haccs[1][...]
+        else:
+            hv = _FF_ACTS[act](haccs[0][...])
+        hv = hv.astype(x1.dtype)
+        acc1[...] += dot(hv, wd1, sd1)
+        acc2[...] += dot(hv, wd2, sd2)
 
     @pl.when(jnp.logical_and(j == nj - 1, k == nk - 1))
     def _flush():
-        z1_ref[:, 0, :] = acc1_ref[...].astype(z1_ref.dtype)
-        z2_ref[:, 0, :] = acc2_ref[...].astype(z2_ref.dtype)
-
-
-def _ff_kernel_swiglu(x1_ref, x2_ref, wg1_ref, wg2_ref, wu1_ref, wu2_ref,
-                      wd1_ref, wd2_ref, z1_ref, z2_ref, gacc_ref, hacc_ref,
-                      acc1_ref, acc2_ref, *, nj: int, nk: int):
-    """SwiGLU body: TWO up accumulators (gate + up) share the k loop; the
-    gated product forms in-register at the k flush."""
-    j = pl.program_id(3)
-    k = pl.program_id(4)
-
-    @pl.when(jnp.logical_and(j == 0, k == 0))
-    def _init_down():
-        acc1_ref[...] = jnp.zeros_like(acc1_ref)
-        acc2_ref[...] = jnp.zeros_like(acc2_ref)
-
-    @pl.when(k == 0)
-    def _init_up():
-        gacc_ref[...] = jnp.zeros_like(gacc_ref)
-        hacc_ref[...] = jnp.zeros_like(hacc_ref)
-
-    dn = (((1,), (1,)), ((), ()))
-    gacc_ref[...] += jax.lax.dot_general(
-        x1_ref[:, 0, :], wg1_ref[0], dn, preferred_element_type=jnp.float32
-    )
-    gacc_ref[...] += jax.lax.dot_general(
-        x2_ref[:, 0, :], wg2_ref[0], dn, preferred_element_type=jnp.float32
-    )
-    hacc_ref[...] += jax.lax.dot_general(
-        x1_ref[:, 0, :], wu1_ref[0], dn, preferred_element_type=jnp.float32
-    )
-    hacc_ref[...] += jax.lax.dot_general(
-        x2_ref[:, 0, :], wu2_ref[0], dn, preferred_element_type=jnp.float32
-    )
-
-    @pl.when(k == nk - 1)
-    def _act_and_down():
-        h = (jax.nn.silu(gacc_ref[...]) * hacc_ref[...]).astype(x1_ref.dtype)
-        acc1_ref[...] += jax.lax.dot_general(
-            h, wd1_ref[0], dn, preferred_element_type=jnp.float32
-        )
-        acc2_ref[...] += jax.lax.dot_general(
-            h, wd2_ref[0], dn, preferred_element_type=jnp.float32
-        )
-
-    @pl.when(jnp.logical_and(j == nj - 1, k == nk - 1))
-    def _flush():
-        z1_ref[:, 0, :] = acc1_ref[...].astype(z1_ref.dtype)
-        z2_ref[:, 0, :] = acc2_ref[...].astype(z2_ref.dtype)
+        z1[...] = acc1[...].astype(z1.dtype)
+        z2[...] = acc2[...].astype(z2.dtype)
 
 
 @functools.partial(
     jax.jit, static_argnames=("bB", "bO", "bJ", "bK", "act", "interpret")
 )
-def _dyad_ff_impl(x1, x2, weights, *, bB: int, bO: int, bJ: int, bK: int,
-                  act: str, interpret: bool):
-    B, n, d_in = x1.shape
-    gated = act == "swiglu"
-    wd1 = weights[-2]
-    d_ffb = wd1.shape[2]
-    d_out = wd1.shape[1]
-    nj = d_ffb // bJ
-    nk = d_in // bK
-    grid = (n, B // bB, d_out // bO, nj, nk)
+def _ff_impl(x1, x2, weights, scales, *, bB: int, bO: int, bJ: int,
+             bK: int, act: str, interpret: bool):
+    """x1, x2: block-major (n, B, d_in).  weights: the up weights
+    (n, d_ff_b, d_in) then wd1, wd2 (n, d_out, d_ff_b).  scales: () or one
+    (n, 1, rows) sidecar per weight, same order."""
+    n, B, d_in = x1.shape
+    n_up = 4 if act == "swiglu" else 2
+    d_out, d_ffb = weights[-1].shape[1:]
+    nj, nk = d_ffb // bJ, d_in // bK
 
-    x_spec = pl.BlockSpec((bB, 1, bK), lambda g, b, o, j, k: (b, g, k))
-    wu_spec = pl.BlockSpec((1, bJ, bK), lambda g, b, o, j, k: (g, j, k))
-    wd_spec = pl.BlockSpec((1, bO, bJ), lambda g, b, o, j, k: (g, o, j))
-    z_spec = pl.BlockSpec((bB, 1, bO), lambda g, b, o, j, k: (b, g, o))
-    out_sds = jax.ShapeDtypeStruct((B, n, d_out), x1.dtype)
-
-    n_up = 4 if gated else 2
-    in_specs = [x_spec, x_spec] + [wu_spec] * n_up + [wd_spec, wd_spec]
-    scratch = ([pltpu.VMEM((bB, bJ), jnp.float32)] * (2 if gated else 1)
+    x_spec = pl.BlockSpec((_SQ, bB, bK), lambda g, b, o, j, k: (g, b, k))
+    wu_spec = pl.BlockSpec((_SQ, bJ, bK), lambda g, b, o, j, k: (g, j, k))
+    wd_spec = pl.BlockSpec((_SQ, bO, bJ), lambda g, b, o, j, k: (g, o, j))
+    su_spec = pl.BlockSpec((_SQ, 1, bJ), lambda g, b, o, j, k: (g, 0, j))
+    sd_spec = pl.BlockSpec((_SQ, 1, bO), lambda g, b, o, j, k: (g, 0, o))
+    z_spec = pl.BlockSpec((_SQ, bB, bO), lambda g, b, o, j, k: (g, b, o))
+    in_specs = [x_spec] * 2 + [wu_spec] * n_up + [wd_spec] * 2
+    if scales:
+        in_specs += [su_spec] * n_up + [sd_spec] * 2
+    out_sds = jax.ShapeDtypeStruct((n, B, d_out), x1.dtype)
+    scratch = ([pltpu.VMEM((bB, bJ), jnp.float32)] * (n_up // 2)
                + [pltpu.VMEM((bB, bO), jnp.float32)] * 2)
-    body = (functools.partial(_ff_kernel_swiglu, nj=nj, nk=nk) if gated
-            else functools.partial(_ff_kernel, nj=nj, nk=nk, act=act))
+    body = functools.partial(_ff_kernel, nj=nj, nk=nk, act=act,
+                             quant=bool(scales))
 
-    return pl.pallas_call(
+    return tuple(pl.pallas_call(
         body,
-        grid=grid,
+        grid=(n, B // bB, d_out // bO, nj, nk),
         in_specs=in_specs,
         out_specs=[z_spec, z_spec],
         out_shape=[out_sds, out_sds],
         scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary", "arbitrary"),
-        ),
+        compiler_params=_compiler_params(3, 2),
         interpret=interpret,
-    )(x1, x2, *weights)
+    )(x1, x2, *weights, *scales))
+
+
+def _run_ff(op: str, x1, x2, ups, downs, s_ups, s_downs, act: str, blocks,
+            *, key_dtype, interpret: bool):
+    """Plan, pad, go block-major, run the megakernel, come back.  ``ups``
+    are the (gate then) up weights, ``downs`` (wd1, wd2); the scale tuples
+    are empty for the unquantized body."""
+    B, n, d_in = x1.shape
+    d_ffb = ups[0].shape[1]
+    d_out = downs[0].shape[1]
+    bb, bo, bk, bj = resolve_ff_blocks(op, B, n, d_in, d_out, d_ffb,
+                                       key_dtype, *blocks)
+    plan = plan_ff_tiles(B, d_out, d_ffb, d_in, bb, bo, bj, bk)
+    db, do = plan.padded_b - B, plan.padded_o - d_out
+    dj, dk = plan.padded_j - d_ffb, plan.padded_k - d_in
+    x1, x2 = (_pad(_block_major(x), (0, db, dk)) for x in (x1, x2))
+    weights = (tuple(_pad(w, (0, dj, dk)) for w in ups)
+               + tuple(_pad(w, (0, do, dj)) for w in downs))
+    scales = (tuple(_pad(s[:, None, :], (0, 0, dj)) for s in s_ups)
+              + tuple(_pad(s[:, None, :], (0, 0, do)) for s in s_downs))
+    z1, z2 = _ff_impl(x1, x2, weights, scales, bB=plan.bB, bO=plan.bO,
+                      bJ=plan.bJ, bK=plan.bK, act=act, interpret=interpret)
+    return tuple(_block_major(z[:, :B, :d_out]) for z in (z1, z2))
+
+
+def _check_ff_act(act: str, wg1, wg2):
+    gated = act == "swiglu"
+    if gated != (wg1 is not None) or gated != (wg2 is not None):
+        raise ValueError("wg1/wg2 must be passed exactly when act='swiglu'")
+    if act not in _FF_ACTS and not gated:
+        raise ValueError(f"unsupported megakernel activation {act!r}")
+    return gated
 
 
 def dyad_ff_fused(
@@ -840,39 +702,15 @@ def dyad_ff_fused(
     ``dyad_ff_fused_swiglu`` op key (which carries d_ff); explicit
     ``block_*`` arguments override.
     """
-    gated = act == "swiglu"
-    if gated != (wg1 is not None) or gated != (wg2 is not None):
-        raise ValueError("wg1/wg2 must be passed exactly when act='swiglu'")
-    if act not in _FF_ACTS and not gated:
-        raise ValueError(f"unsupported megakernel activation {act!r}")
-    B, n, d_in = x1.shape
-    _, d_ffb, _ = wu1.shape
-    _, d_out, _ = wd1.shape
-    op = "dyad_ff_fused_swiglu" if gated else "dyad_ff_fused"
-    bb, bo, bk, bj = resolve_ff_blocks(op, B, n, d_in, d_out, d_ffb,
-                                       x1.dtype, block_b, block_o, block_k,
-                                       block_j)
-    plan = plan_ff_tiles(B, d_out, d_ffb, d_in, bb, bo, bj, bk)
-    db, do = plan.padded_b - B, plan.padded_o - d_out
-    dj, dk = plan.padded_j - d_ffb, plan.padded_k - d_in
-    if db or dk:
-        x1 = jnp.pad(x1, ((0, db), (0, 0), (0, dk)))
-        x2 = jnp.pad(x2, ((0, db), (0, 0), (0, dk)))
+    gated = _check_ff_act(act, wg1, wg2)
     ups = (wg1, wg2, wu1, wu2) if gated else (wu1, wu2)
-    if dj or dk:
-        ups = tuple(jnp.pad(w, ((0, 0), (0, dj), (0, dk))) for w in ups)
-    downs = (wd1, wd2)
-    if do or dj:
-        downs = tuple(jnp.pad(w, ((0, 0), (0, do), (0, dj))) for w in downs)
-    z1, z2 = _dyad_ff_impl(x1, x2, ups + downs, bB=plan.bB, bO=plan.bO,
-                           bJ=plan.bJ, bK=plan.bK, act=act,
-                           interpret=interpret)
-    if db or do:
-        z1, z2 = z1[:B, :, :d_out], z2[:B, :, :d_out]
-    return z1, z2
+    op = "dyad_ff_fused_swiglu" if gated else "dyad_ff_fused"
+    return _run_ff(op, x1, x2, ups, (wd1, wd2), (), (), act,
+                   (block_b, block_o, block_k, block_j),
+                   key_dtype=x1.dtype, interpret=interpret)
 
 
-# -- quantized bodies: int8/fp8 weight streams, dequant at the VMEM load ------
+# -- quantized twins: int8/fp8 weight streams, dequant at the VMEM load -------
 #
 # Weight tiles stream in their QUANTIZED dtype (1 byte/elem — the HBM
 # stream the forward is bound on shrinks 2-4x); the per-(block, out_row)
@@ -886,106 +724,9 @@ def dyad_ff_fused(
 # magnitudes <= 127 and every fp8 value are exactly representable in bf16
 # and fp32, so the cast is lossless) and never exists dequantized in HBM.
 # Activation/hidden dataflow, grids, and tile planning are identical to
-# the unquantized bodies; the ops autotune under ``*_w8`` keys whose dtype
-# field carries the weight payload dtype.
-
-
-def _dyad_kernel_q(x1_ref, x2_ref, w1_ref, w2_ref, s1_ref, s2_ref, o_ref,
-                   acc_ref, *, nk: int):
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    dn = (((1,), (1,)), ((), ()))
-    acc_ref[...] += jax.lax.dot_general(
-        x1_ref[:, 0, :], w1_ref[0].astype(x1_ref.dtype), dn,
-        preferred_element_type=jnp.float32) * s1_ref[0]
-    acc_ref[...] += jax.lax.dot_general(
-        x2_ref[:, 0, :], w2_ref[0].astype(x2_ref.dtype), dn,
-        preferred_element_type=jnp.float32) * s2_ref[0]
-
-    @pl.when(k == nk - 1)
-    def _flush():
-        o_ref[:, 0, :] = acc_ref[...].astype(o_ref.dtype)
-
-
-def _dyad_kernel_two_q(x1_ref, x2_ref, w1_ref, w2_ref, s1_ref, s2_ref,
-                       o1_ref, o2_ref, acc1_ref, acc2_ref, *, nk: int):
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
-    def _init():
-        acc1_ref[...] = jnp.zeros_like(acc1_ref)
-        acc2_ref[...] = jnp.zeros_like(acc2_ref)
-
-    dn = (((1,), (1,)), ((), ()))
-    acc1_ref[...] += jax.lax.dot_general(
-        x1_ref[:, 0, :], w1_ref[0].astype(x1_ref.dtype), dn,
-        preferred_element_type=jnp.float32) * s1_ref[0]
-    acc2_ref[...] += jax.lax.dot_general(
-        x2_ref[:, 0, :], w2_ref[0].astype(x2_ref.dtype), dn,
-        preferred_element_type=jnp.float32) * s2_ref[0]
-
-    @pl.when(k == nk - 1)
-    def _flush():
-        o1_ref[:, 0, :] = acc1_ref[...].astype(o1_ref.dtype)
-        o2_ref[:, 0, :] = acc2_ref[...].astype(o2_ref.dtype)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("bB", "bO", "bK", "fused", "interpret")
-)
-def _dyad_mm_q_impl(x1, x2, w1, w2, s1, s2, *, bB: int, bO: int, bK: int,
-                    fused: bool, interpret: bool):
-    B, n, d_in = x1.shape
-    _, d_out, _ = w1.shape
-    nk = d_in // bK
-    grid = (n, B // bB, d_out // bO, nk)
-
-    x_spec = pl.BlockSpec((bB, 1, bK), lambda g, b, o, k: (b, g, k))
-    w_spec = pl.BlockSpec((1, bO, bK), lambda g, b, o, k: (g, o, k))
-    s_spec = pl.BlockSpec((1, bO), lambda g, b, o, k: (g, o))
-    o_spec = pl.BlockSpec((bB, 1, bO), lambda g, b, o, k: (b, g, o))
-    out_sds = jax.ShapeDtypeStruct((B, n, d_out), x1.dtype)
-    acc = pltpu.VMEM((bB, bO), jnp.float32)
-    in_specs = [x_spec, x_spec, w_spec, w_spec, s_spec, s_spec]
-    params = _CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel",
-                             "arbitrary"))
-
-    if fused:
-        return pl.pallas_call(
-            functools.partial(_dyad_kernel_q, nk=nk),
-            grid=grid, in_specs=in_specs, out_specs=o_spec,
-            out_shape=out_sds, scratch_shapes=[acc],
-            compiler_params=params, interpret=interpret,
-        )(x1, x2, w1, w2, s1, s2)
-    return pl.pallas_call(
-        functools.partial(_dyad_kernel_two_q, nk=nk),
-        grid=grid, in_specs=in_specs, out_specs=[o_spec, o_spec],
-        out_shape=[out_sds, out_sds], scratch_shapes=[acc, acc],
-        compiler_params=params, interpret=interpret,
-    )(x1, x2, w1, w2, s1, s2)
-
-
-def _prep_quant_mm(op, x1, x2, w1, w2, s1, s2, block_b, block_o, block_k):
-    B, n, d_in = x1.shape
-    _, d_out, _ = w1.shape
-    # the op key's dtype field carries the WEIGHT payload dtype (int8/fp8):
-    # quantized tiles stream fewer bytes, so their tuned tiles must never
-    # collide with the unquantized entries for the same shape.
-    bb, bo, bk = resolve_blocks(op, B, n, d_in, d_out, w1.dtype,
-                                block_b, block_o, block_k)
-    plan = plan_tiles(B, d_out, d_in, bb, bo, bk)
-    x1, x2, w1, w2 = _pad_inputs(plan, x1, x2, w1, w2)
-    do = plan.padded_o - d_out
-    if do:
-        # padded out rows hold zero weights; their scale value is moot
-        s1 = jnp.pad(s1, ((0, 0), (0, do)))
-        s2 = jnp.pad(s2, ((0, 0), (0, do)))
-    return x1, x2, w1, w2, s1, s2, plan
+# the unquantized kernels (the same bodies with ``quant=True``); the ops
+# autotune under ``*_w8`` keys whose dtype field carries the weight
+# payload dtype, so quantized tiles never collide with unquantized ones.
 
 
 def dyad_mm_blocks_q(
@@ -1005,16 +746,9 @@ def dyad_mm_blocks_q(
 
     w1, w2: (n_dyad, d_out, d_in) int8/fp8 payloads; s1, s2: (n_dyad,
     d_out) fp32 per-(block, out_row) scales.  Output in x1's dtype."""
-    B, n, d_in = x1.shape
-    _, d_out, _ = w1.shape
-    x1, x2, w1, w2, s1, s2, plan = _prep_quant_mm(
-        "dyad_mm_blocks_w8", x1, x2, w1, w2, s1, s2,
-        block_b, block_o, block_k)
-    out = _dyad_mm_q_impl(x1, x2, w1, w2, s1, s2, bB=plan.bB, bO=plan.bO,
-                          bK=plan.bK, fused=True, interpret=interpret)
-    if plan.padded_b != B or plan.padded_o != d_out:
-        out = out[:B, :, :d_out]
-    return out
+    return _run_mm("dyad_mm_blocks_w8", x1, x2, w1, w2, (s1, s2),
+                   (block_b, block_o, block_k), two=False, dgrad=False,
+                   key_dtype=w1.dtype, interpret=interpret)
 
 
 def dyad_mm_blocks_two_q(
@@ -1031,149 +765,9 @@ def dyad_mm_blocks_two_q(
     interpret: bool = False,
 ):
     """As :func:`dyad_mm_blocks_q` but returns (z1, z2) separately (OT/DT)."""
-    B, n, d_in = x1.shape
-    _, d_out, _ = w1.shape
-    x1, x2, w1, w2, s1, s2, plan = _prep_quant_mm(
-        "dyad_mm_blocks_two_w8", x1, x2, w1, w2, s1, s2,
-        block_b, block_o, block_k)
-    z1, z2 = _dyad_mm_q_impl(x1, x2, w1, w2, s1, s2, bB=plan.bB, bO=plan.bO,
-                             bK=plan.bK, fused=False, interpret=interpret)
-    if plan.padded_b != B or plan.padded_o != d_out:
-        z1, z2 = z1[:B, :, :d_out], z2[:B, :, :d_out]
-    return z1, z2
-
-
-def _ff_kernel_q(x1_ref, x2_ref, wu1_ref, wu2_ref, wd1_ref, wd2_ref,
-                 su1_ref, su2_ref, sd1_ref, sd2_ref, z1_ref, z2_ref,
-                 hacc_ref, acc1_ref, acc2_ref, *, nj: int, nk: int,
-                 act: str):
-    j = pl.program_id(3)
-    k = pl.program_id(4)
-
-    @pl.when(jnp.logical_and(j == 0, k == 0))
-    def _init_down():
-        acc1_ref[...] = jnp.zeros_like(acc1_ref)
-        acc2_ref[...] = jnp.zeros_like(acc2_ref)
-
-    @pl.when(k == 0)
-    def _init_up():
-        hacc_ref[...] = jnp.zeros_like(hacc_ref)
-
-    dn = (((1,), (1,)), ((), ()))
-    hacc_ref[...] += jax.lax.dot_general(
-        x1_ref[:, 0, :], wu1_ref[0].astype(x1_ref.dtype), dn,
-        preferred_element_type=jnp.float32) * su1_ref[0]
-    hacc_ref[...] += jax.lax.dot_general(
-        x2_ref[:, 0, :], wu2_ref[0].astype(x2_ref.dtype), dn,
-        preferred_element_type=jnp.float32) * su2_ref[0]
-
-    @pl.when(k == nk - 1)
-    def _act_and_down():
-        h = _FF_ACTS[act](hacc_ref[...]).astype(x1_ref.dtype)
-        acc1_ref[...] += jax.lax.dot_general(
-            h, wd1_ref[0].astype(h.dtype), dn,
-            preferred_element_type=jnp.float32) * sd1_ref[0]
-        acc2_ref[...] += jax.lax.dot_general(
-            h, wd2_ref[0].astype(h.dtype), dn,
-            preferred_element_type=jnp.float32) * sd2_ref[0]
-
-    @pl.when(jnp.logical_and(j == nj - 1, k == nk - 1))
-    def _flush():
-        z1_ref[:, 0, :] = acc1_ref[...].astype(z1_ref.dtype)
-        z2_ref[:, 0, :] = acc2_ref[...].astype(z2_ref.dtype)
-
-
-def _ff_kernel_swiglu_q(x1_ref, x2_ref, wg1_ref, wg2_ref, wu1_ref, wu2_ref,
-                        wd1_ref, wd2_ref, sg1_ref, sg2_ref, su1_ref,
-                        su2_ref, sd1_ref, sd2_ref, z1_ref, z2_ref,
-                        gacc_ref, hacc_ref, acc1_ref, acc2_ref, *,
-                        nj: int, nk: int):
-    j = pl.program_id(3)
-    k = pl.program_id(4)
-
-    @pl.when(jnp.logical_and(j == 0, k == 0))
-    def _init_down():
-        acc1_ref[...] = jnp.zeros_like(acc1_ref)
-        acc2_ref[...] = jnp.zeros_like(acc2_ref)
-
-    @pl.when(k == 0)
-    def _init_up():
-        gacc_ref[...] = jnp.zeros_like(gacc_ref)
-        hacc_ref[...] = jnp.zeros_like(hacc_ref)
-
-    dn = (((1,), (1,)), ((), ()))
-    gacc_ref[...] += jax.lax.dot_general(
-        x1_ref[:, 0, :], wg1_ref[0].astype(x1_ref.dtype), dn,
-        preferred_element_type=jnp.float32) * sg1_ref[0]
-    gacc_ref[...] += jax.lax.dot_general(
-        x2_ref[:, 0, :], wg2_ref[0].astype(x2_ref.dtype), dn,
-        preferred_element_type=jnp.float32) * sg2_ref[0]
-    hacc_ref[...] += jax.lax.dot_general(
-        x1_ref[:, 0, :], wu1_ref[0].astype(x1_ref.dtype), dn,
-        preferred_element_type=jnp.float32) * su1_ref[0]
-    hacc_ref[...] += jax.lax.dot_general(
-        x2_ref[:, 0, :], wu2_ref[0].astype(x2_ref.dtype), dn,
-        preferred_element_type=jnp.float32) * su2_ref[0]
-
-    @pl.when(k == nk - 1)
-    def _act_and_down():
-        h = (jax.nn.silu(gacc_ref[...]) * hacc_ref[...]).astype(x1_ref.dtype)
-        acc1_ref[...] += jax.lax.dot_general(
-            h, wd1_ref[0].astype(h.dtype), dn,
-            preferred_element_type=jnp.float32) * sd1_ref[0]
-        acc2_ref[...] += jax.lax.dot_general(
-            h, wd2_ref[0].astype(h.dtype), dn,
-            preferred_element_type=jnp.float32) * sd2_ref[0]
-
-    @pl.when(jnp.logical_and(j == nj - 1, k == nk - 1))
-    def _flush():
-        z1_ref[:, 0, :] = acc1_ref[...].astype(z1_ref.dtype)
-        z2_ref[:, 0, :] = acc2_ref[...].astype(z2_ref.dtype)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("bB", "bO", "bJ", "bK", "act", "interpret")
-)
-def _dyad_ff_q_impl(x1, x2, weights, scales, *, bB: int, bO: int, bJ: int,
-                    bK: int, act: str, interpret: bool):
-    B, n, d_in = x1.shape
-    gated = act == "swiglu"
-    wd1 = weights[-2]
-    d_ffb = wd1.shape[2]
-    d_out = wd1.shape[1]
-    nj = d_ffb // bJ
-    nk = d_in // bK
-    grid = (n, B // bB, d_out // bO, nj, nk)
-
-    x_spec = pl.BlockSpec((bB, 1, bK), lambda g, b, o, j, k: (b, g, k))
-    wu_spec = pl.BlockSpec((1, bJ, bK), lambda g, b, o, j, k: (g, j, k))
-    wd_spec = pl.BlockSpec((1, bO, bJ), lambda g, b, o, j, k: (g, o, j))
-    su_spec = pl.BlockSpec((1, bJ), lambda g, b, o, j, k: (g, j))
-    sd_spec = pl.BlockSpec((1, bO), lambda g, b, o, j, k: (g, o))
-    z_spec = pl.BlockSpec((bB, 1, bO), lambda g, b, o, j, k: (b, g, o))
-    out_sds = jax.ShapeDtypeStruct((B, n, d_out), x1.dtype)
-
-    n_up = 4 if gated else 2
-    in_specs = ([x_spec, x_spec] + [wu_spec] * n_up + [wd_spec, wd_spec]
-                + [su_spec] * n_up + [sd_spec, sd_spec])
-    scratch = ([pltpu.VMEM((bB, bJ), jnp.float32)] * (2 if gated else 1)
-               + [pltpu.VMEM((bB, bO), jnp.float32)] * 2)
-    body = (functools.partial(_ff_kernel_swiglu_q, nj=nj, nk=nk) if gated
-            else functools.partial(_ff_kernel_q, nj=nj, nk=nk, act=act))
-
-    return pl.pallas_call(
-        body,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[z_spec, z_spec],
-        out_shape=[out_sds, out_sds],
-        scratch_shapes=scratch,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(x1, x2, *weights, *scales)
+    return _run_mm("dyad_mm_blocks_two_w8", x1, x2, w1, w2, (s1, s2),
+                   (block_b, block_o, block_k), two=True, dgrad=False,
+                   key_dtype=w1.dtype, interpret=interpret)
 
 
 def dyad_ff_fused_q(
@@ -1206,41 +800,12 @@ def dyad_ff_fused_q(
     scales.  Activation/hidden dataflow is IDENTICAL to the unquantized
     megakernel — only the weight streams shrink.  Tiles resolve under the
     ``dyad_ff_fused[_swiglu]_w8`` op keys (dtype field = payload dtype)."""
-    gated = act == "swiglu"
-    if gated != (wg1 is not None) or gated != (wg2 is not None):
-        raise ValueError("wg1/wg2 must be passed exactly when act='swiglu'")
+    gated = _check_ff_act(act, wg1, wg2)
     if gated and (sg1 is None or sg2 is None):
         raise ValueError("sg1/sg2 must be passed when act='swiglu'")
-    if act not in _FF_ACTS and not gated:
-        raise ValueError(f"unsupported megakernel activation {act!r}")
-    B, n, d_in = x1.shape
-    _, d_ffb, _ = wu1.shape
-    _, d_out, _ = wd1.shape
-    op = "dyad_ff_fused_swiglu_w8" if gated else "dyad_ff_fused_w8"
-    bb, bo, bk, bj = resolve_ff_blocks(op, B, n, d_in, d_out, d_ffb,
-                                       wu1.dtype, block_b, block_o, block_k,
-                                       block_j)
-    plan = plan_ff_tiles(B, d_out, d_ffb, d_in, bb, bo, bj, bk)
-    db, do = plan.padded_b - B, plan.padded_o - d_out
-    dj, dk = plan.padded_j - d_ffb, plan.padded_k - d_in
-    if db or dk:
-        x1 = jnp.pad(x1, ((0, db), (0, 0), (0, dk)))
-        x2 = jnp.pad(x2, ((0, db), (0, 0), (0, dk)))
     ups = (wg1, wg2, wu1, wu2) if gated else (wu1, wu2)
     s_ups = (sg1, sg2, su1, su2) if gated else (su1, su2)
-    if dj or dk:
-        ups = tuple(jnp.pad(w, ((0, 0), (0, dj), (0, dk))) for w in ups)
-    if dj:
-        s_ups = tuple(jnp.pad(s, ((0, 0), (0, dj))) for s in s_ups)
-    downs = (wd1, wd2)
-    s_downs = (sd1, sd2)
-    if do or dj:
-        downs = tuple(jnp.pad(w, ((0, 0), (0, do), (0, dj))) for w in downs)
-    if do:
-        s_downs = tuple(jnp.pad(s, ((0, 0), (0, do))) for s in s_downs)
-    z1, z2 = _dyad_ff_q_impl(x1, x2, ups + downs, s_ups + s_downs,
-                             bB=plan.bB, bO=plan.bO, bJ=plan.bJ, bK=plan.bK,
-                             act=act, interpret=interpret)
-    if db or do:
-        z1, z2 = z1[:B, :, :d_out], z2[:B, :, :d_out]
-    return z1, z2
+    op = "dyad_ff_fused_swiglu_w8" if gated else "dyad_ff_fused_w8"
+    return _run_ff(op, x1, x2, ups, (wd1, wd2), s_ups, (sd1, sd2), act,
+                   (block_b, block_o, block_k, block_j),
+                   key_dtype=wu1.dtype, interpret=interpret)

@@ -57,7 +57,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dyad_mm import _CompilerParams, _largest_divisor, _plan_axis
+from repro.kernels.dyad_mm import _largest_divisor, _plan_axis
 
 NEG_INF = -1e30
 _TINY = 1e-30
@@ -218,8 +218,8 @@ def _prefill_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, *rest,
         l = l_s[:, :1]
         o_ref[0, 0] = (acc[...] / jnp.maximum(l, _TINY)).astype(o_ref.dtype)
         if save_lse:
-            lse_ref[0, 0, :] = (m_s[:, 0]
-                                + jnp.log(jnp.maximum(l_s[:, 0], _TINY)))
+            lse_ref[0, 0] = (m_s[:, :1]
+                             + jnp.log(jnp.maximum(l_s[:, :1], _TINY)))
 
 
 @functools.partial(
@@ -244,8 +244,8 @@ def _prefill_impl(q, k, v, qoff, koff, *, bQ, bT, G, causal, window, t_real,
     out_specs, out_shapes = [o_spec], [out_shape]
     if save_lse:
         out_specs.append(pl.BlockSpec(
-            (1, 1, bQG), lambda b, kh, qi, ki, qo, ko: (b, kh, qi)))
-        out_shapes.append(jax.ShapeDtypeStruct((B, K, SG), jnp.float32))
+            (1, 1, bQG, 1), lambda b, kh, qi, ki, qo, ko: (b, kh, qi, 0)))
+        out_shapes.append(jax.ShapeDtypeStruct((B, K, SG, 1), jnp.float32))
 
     scale = 1.0 / float(h) ** 0.5
     body = functools.partial(
@@ -265,13 +265,13 @@ def _prefill_impl(q, k, v, qoff, koff, *, bQ, bT, G, causal, window, t_real,
             ],
         ),
         out_shape=out_shapes,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
         interpret=interpret,
     )(qoff, koff, q, k, v)
-    return (out[0], out[1]) if save_lse else (out[0], None)
+    return (out[0], out[1][..., 0]) if save_lse else (out[0], None)
 
 
 def _plan_attn(S: int, T: int, block_q: int, block_k: int):
@@ -351,12 +351,11 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32) * scale
         mask = _tile_mask(qoff_ref[b], koff_ref[b], qi, ki, bQ, bT, G,
                           t_real, causal, window)
-        lse = lse_ref[0, 0, :][:, None]                     # (bQ*G, 1)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
+        p = jnp.where(mask, jnp.exp(s - lse_ref[0, 0]), 0.0)  # (bQ*G, 1) rows
         dp = jax.lax.dot_general(
             do_ref[0, 0], v_ref[0, 0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0, :][:, None]) * scale
+        ds = p * (dp - delta_ref[0, 0]) * scale
         acc[...] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -387,8 +386,8 @@ def _dq_impl(q, k, v, do, lse, delta, qoff, koff, *, bQ, bT, G, causal,
                           lambda b, kh, qi, ki, qo, ko: (b, kh, qi, 0))
     kv_spec = pl.BlockSpec((1, 1, bT, h),
                            _kv_index_map(causal, window, bQ, bT, nt))
-    row_spec = pl.BlockSpec((1, 1, bQG),
-                            lambda b, kh, qi, ki, qo, ko: (b, kh, qi))
+    row_spec = pl.BlockSpec((1, 1, bQG, 1),
+                            lambda b, kh, qi, ki, qo, ko: (b, kh, qi, 0))
     scale = 1.0 / float(h) ** 0.5
     body = functools.partial(_dq_kernel, G=G, bQ=bQ, bT=bT, t_real=t_real,
                              causal=causal, window=window, scale=scale)
@@ -402,7 +401,7 @@ def _dq_impl(q, k, v, do, lse, delta, qoff, koff, *, bQ, bT, G, causal,
             scratch_shapes=[pltpu.VMEM((bQG, h), jnp.float32)],
         ),
         out_shape=[jax.ShapeDtypeStruct((B, K, SG, h), q.dtype)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -457,8 +456,7 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32) * scale
         mask = _tile_mask(qoff_ref[b], koff_ref[b], qi, ki, bQ, bT, G,
                           t_real, causal, window)
-        lse = lse_ref[0, 0, :][:, None]
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
+        p = jnp.where(mask, jnp.exp(s - lse_ref[0, 0]), 0.0)
         do = do_ref[0, 0]
         # dv += P^T · dO  — contract the q rows
         vacc[...] += jax.lax.dot_general(
@@ -467,7 +465,7 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dp = jax.lax.dot_general(
             do, v_ref[0, 0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0, :][:, None]) * scale
+        ds = p * (dp - delta_ref[0, 0]) * scale
         kacc[...] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -499,12 +497,8 @@ def _dkv_impl(q, k, v, do, lse, delta, qoff, koff, *, bQ, bT, G, causal,
                           _q_index_map(causal, window, bQ, bT, nq))
     kv_spec = pl.BlockSpec((1, 1, bT, h),
                            lambda b, kh, ki, qi, qo, ko: (b, kh, ki, 0))
-
-    def row_index(b, kh, ki, qi, qo, ko):
-        return _q_index_map(causal, window, bQ, bT, nq)(
-            b, kh, ki, qi, qo, ko)[:3]
-
-    row_spec = pl.BlockSpec((1, 1, bQG), row_index)
+    row_spec = pl.BlockSpec((1, 1, bQG, 1),
+                            _q_index_map(causal, window, bQ, bT, nq))
     scale = 1.0 / float(h) ** 0.5
     body = functools.partial(_dkv_kernel, G=G, bQ=bQ, bT=bT, t_real=t_real,
                              causal=causal, window=window, scale=scale)
@@ -520,7 +514,7 @@ def _dkv_impl(q, k, v, do, lse, delta, qoff, koff, *, bQ, bT, G, causal,
                             pltpu.VMEM((bT, h), jnp.float32)],
         ),
         out_shape=[out_sds, out_sds],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -569,6 +563,8 @@ def flash_prefill_grads(
                       ((0, 0), (0, 0), (0, Sp - S), (0, 0)),
                       constant_values=-NEG_INF).reshape(B, K, Sp * G)
     qoff, koff = _as_offsets(q_off, B), _as_offsets(k_off, B)
+    # the kernels read lse/delta as (B, K, S*G, 1) row columns
+    lse, delta = lse[..., None], delta[..., None]
     kw = dict(bQ=bQ, bT=bT, G=G, causal=causal, window=window, t_real=T,
               interpret=interpret)
     dq = _dq_impl(qf, kf, vf, dof, lse, delta, qoff, koff, **kw)
@@ -660,7 +656,7 @@ def _decode_impl(q, k, v, idx, *, bT, l_real, window, interpret):
             ],
         ),
         out_shape=[jax.ShapeDtypeStruct((B, K, G, h), q.dtype)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -780,14 +776,15 @@ def _paged_kv_index_map(bT: int, tiles_per_page: int):
 
 
 def _paged_scale_index_map(bT: int, tiles_per_page: int):
-    """The 3-D twin of :func:`_paged_kv_index_map` for the per-token-row
-    scale pools ``(n_pages, K, P)`` — the SAME block-table gather routes
-    the (1, 1, bT) scale tile alongside its quantized K/V tile."""
+    """The twin of :func:`_paged_kv_index_map` for the per-token-row scale
+    pools, laid out ``(n_pages, K, 1, P)`` so a tile is a ``(1, bT)`` row —
+    the SAME block-table gather routes it alongside its quantized K/V
+    tile."""
 
     def index(b, kh, t, idx_ref, bt_ref):
         t_eff = jnp.minimum(t, jnp.maximum(idx_ref[b], 0) // bT)
         blk = t_eff // tiles_per_page
-        return (bt_ref[b, blk], kh, t_eff % tiles_per_page)
+        return (bt_ref[b, blk], kh, 0, t_eff % tiles_per_page)
 
     return index
 
@@ -818,10 +815,10 @@ def _decode_paged_kernel_q(idx_ref, bt_ref, q_ref, k_ref, v_ref, sk_ref,
         G = q_ref.shape[2]
         q = q_ref[0, 0]                                   # (G, h)
         k = k_ref[0, 0].astype(q.dtype)                   # (bT, h) dequant
-        sk = sk_ref[0, 0]                                 # (bT,) fp32
+        sk = sk_ref[0, 0]                                 # (1, bT) fp32
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sk[None, :] * scale
+            preferred_element_type=jnp.float32) * sk * scale
         j = jax.lax.broadcasted_iota(jnp.int32, (G, bT), 1) + t * bT
         mask = jnp.logical_and(j <= idx, j < l_real)
         if window is not None:
@@ -833,9 +830,9 @@ def _decode_paged_kernel_q(idx_ref, bt_ref, q_ref, k_ref, v_ref, sk_ref,
         p = jnp.where(mask, jnp.exp(s - m_next[:, :1]), 0.0)
         l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         m_s[...] = m_next
-        sv = sv_ref[0, 0]                                 # (bT,) fp32
+        sv = sv_ref[0, 0]                                 # (1, bT) fp32
         acc[...] = acc[...] * alpha[:, :1] + jax.lax.dot_general(
-            p * sv[None, :], v_ref[0, 0].astype(jnp.float32),
+            p * sv, v_ref[0, 0].astype(jnp.float32),
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
@@ -873,7 +870,7 @@ def _decode_paged_impl(q, k, v, idx, bt, *, bT, l_real, window, interpret):
             ],
         ),
         out_shape=[jax.ShapeDtypeStruct((B, K, G, h), q.dtype)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -892,7 +889,7 @@ def _decode_paged_q_impl(q, k, v, sk, sv, idx, bt, *, bT, l_real, window,
 
     q_spec = pl.BlockSpec((1, 1, G, h), lambda b, kh, t, i, m: (b, kh, 0, 0))
     kv_spec = pl.BlockSpec((1, 1, bT, h), _paged_kv_index_map(bT, tp))
-    s_spec = pl.BlockSpec((1, 1, bT), _paged_scale_index_map(bT, tp))
+    s_spec = pl.BlockSpec((1, 1, 1, bT), _paged_scale_index_map(bT, tp))
     scale = 1.0 / float(h) ** 0.5
     body = functools.partial(_decode_paged_kernel_q, bT=bT, l_real=l_real,
                              window=window, scale=scale)
@@ -910,7 +907,7 @@ def _decode_paged_q_impl(q, k, v, sk, sv, idx, bt, *, bT, l_real, window,
             ],
         ),
         out_shape=[jax.ShapeDtypeStruct((B, K, G, h), q.dtype)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -973,8 +970,8 @@ def flash_decode_paged(
     if scales_k is not None:
         o = _decode_paged_q_impl(
             q, k, v,
-            scales_k.transpose(0, 2, 1),                  # (NP, K, P)
-            scales_v.transpose(0, 2, 1),
+            scales_k.transpose(0, 2, 1)[:, :, None],      # (NP, K, 1, P)
+            scales_v.transpose(0, 2, 1)[:, :, None],
             _as_offsets(idx, B), jnp.asarray(block_table, jnp.int32),
             bT=bT, l_real=int(l_real), window=window, interpret=interpret)
     else:

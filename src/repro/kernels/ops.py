@@ -129,6 +129,15 @@ def attn_route() -> str:
     return route
 
 
+def _einsum32(spec, a, b):
+    """fp32 contraction of possibly-bf16 operands, for the compiled
+    non-TPU lowerings below.  Upcasting first is exact — the product of
+    two bf16 values fits an fp32 mantissa, so this equals a bf16 x bf16
+    dot accumulated in fp32 — and it sidesteps CPU dot kernels that have
+    no bf16 x bf16 -> fp32 path."""
+    return jnp.einsum(spec, a.astype(jnp.float32), b.astype(jnp.float32))
+
+
 def _bwd_direct(x2d, w1, w2, g2d, variant: str):
     """Compiled non-TPU lowering of the fused kernel backward.
 
@@ -141,25 +150,24 @@ def _bwd_direct(x2d, w1, w2, g2d, variant: str):
     """
     B, f_in = x2d.shape
     n, d_out, d_in = w1.shape
-    f32 = jnp.float32
     x1 = x2d.reshape(B, n, d_in)
     xr = x2d.reshape(B, d_in, n)          # x2[b,g,i] == xr[b,i,g]
     z1 = g2d.reshape(B, n, d_out)
     gr = g2d.reshape(B, d_out, n)         # z2bar[b,g,o] == gr[b,o,g]
 
-    dw1 = jnp.einsum("bgi,bgo->goi", x1, z1, preferred_element_type=f32)
-    dx1 = jnp.einsum("bgo,goi->bgi", z1, w1, preferred_element_type=f32)
+    dw1 = _einsum32("bgi,bgo->goi", x1, z1)
+    dx1 = _einsum32("bgo,goi->bgi", z1, w1)
     if variant == "it":
-        dw2 = jnp.einsum("big,bgo->goi", xr, z1, preferred_element_type=f32)
-        dx2r = jnp.einsum("bgo,goi->big", z1, w2, preferred_element_type=f32)
+        dw2 = _einsum32("big,bgo->goi", xr, z1)
+        dx2r = _einsum32("bgo,goi->big", z1, w2)
         dx = dx1.reshape(B, f_in) + dx2r.reshape(B, f_in)
     elif variant == "ot":
-        dw2 = jnp.einsum("bgi,bog->goi", x1, gr, preferred_element_type=f32)
-        dx2 = jnp.einsum("bog,goi->bgi", gr, w2, preferred_element_type=f32)
+        dw2 = _einsum32("bgi,bog->goi", x1, gr)
+        dx2 = _einsum32("bog,goi->bgi", gr, w2)
         dx = (dx1 + dx2).reshape(B, f_in)
     else:  # "dt"
-        dw2 = jnp.einsum("big,bog->goi", xr, gr, preferred_element_type=f32)
-        dx2r = jnp.einsum("bog,goi->big", gr, w2, preferred_element_type=f32)
+        dw2 = _einsum32("big,bog->goi", xr, gr)
+        dx2r = _einsum32("bog,goi->big", gr, w2)
         dx = dx1.reshape(B, f_in) + dx2r.reshape(B, f_in)
     return dx, dw1, dw2
 
@@ -355,7 +363,6 @@ def _ff_bwd_direct(x, wg, wu, wd, g, act):
     contractions (the BLOCKTRANS operands are read through the free
     ``(B, d, n)`` reshapes), fp32 accumulation, rematerialized hidden —
     no strided view, hidden store, or dx un-view is ever materialized."""
-    f32 = jnp.float32
     n, d_ffb, d_in = wu[0].shape
     d_out = wd[0].shape[1]
     lead = x.shape[:-1]
@@ -372,10 +379,8 @@ def _ff_bwd_direct(x, wg, wu, wd, g, act):
     wd1, wd2 = (w.astype(dt) for w in wd)
 
     def up(w1, w2):
-        pre = (jnp.einsum("bgk,gjk->bgj", x1, w1,
-                          preferred_element_type=f32)
-               + jnp.einsum("bkg,gjk->bgj", xr, w2,
-                            preferred_element_type=f32))
+        pre = (_einsum32("bgk,gjk->bgj", x1, w1)
+               + _einsum32("bkg,gjk->bgj", xr, w2))
         return pre.astype(dt)
 
     u_pre = up(wu1, wu2)
@@ -385,11 +390,10 @@ def _ff_bwd_direct(x, wg, wu, wd, g, act):
     else:
         h, act_vjp = _ff_act_fwd(act, None, u_pre)
 
-    dwd1 = jnp.einsum("bgj,bgo->goj", h, z1, preferred_element_type=f32)
-    dwd2 = jnp.einsum("bgj,bog->goj", h, gr, preferred_element_type=f32)
-    dh = (jnp.einsum("bgo,goj->bgj", z1, wd1, preferred_element_type=f32)
-          + jnp.einsum("bog,goj->bgj", gr, wd2,
-                       preferred_element_type=f32)).astype(dt)
+    dwd1 = _einsum32("bgj,bgo->goj", h, z1)
+    dwd2 = _einsum32("bgj,bog->goj", h, gr)
+    dh = (_einsum32("bgo,goj->bgj", z1, wd1)
+          + _einsum32("bog,goj->bgj", gr, wd2)).astype(dt)
 
     if wg is not None:
         dg_pre, du_pre = act_vjp(dh)
@@ -397,14 +401,12 @@ def _ff_bwd_direct(x, wg, wu, wd, g, act):
         (du_pre,) = act_vjp(dh)
 
     def down_grads(du, w1, w2):
-        dw1 = jnp.einsum("bgk,bgj->gjk", x1, du, preferred_element_type=f32)
-        dw2 = jnp.einsum("bkg,bgj->gjk", xr, du, preferred_element_type=f32)
+        dw1 = _einsum32("bgk,bgj->gjk", x1, du)
+        dw2 = _einsum32("bkg,bgj->gjk", xr, du)
         # component 2's dx is PRODUCED in the permuted layout (bkg): the
         # un-view is a free reshape, never a copy.
-        dx = (jnp.einsum("bgj,gjk->bgk", du, w1,
-                         preferred_element_type=f32).reshape(B, f_in)
-              + jnp.einsum("bgj,gjk->bkg", du, w2,
-                           preferred_element_type=f32).reshape(B, f_in))
+        dx = (_einsum32("bgj,gjk->bgk", du, w1).reshape(B, f_in)
+              + _einsum32("bgj,gjk->bkg", du, w2).reshape(B, f_in))
         return dw1, dw2, dx
 
     dwu1, dwu2, dx = down_grads(du_pre, wu1, wu2)
@@ -604,8 +606,7 @@ def _flash_bwd_direct(q, k, v, o, lse, do, q_off, k_off, causal, window):
     B, S, K, G, h = q.shape
     T = k.shape[1]
     scale = 1.0 / float(h) ** 0.5
-    s = jnp.einsum("bskgh,btkh->bskgt", q, k,
-                   preferred_element_type=f32) * scale
+    s = _einsum32("bskgh,btkh->bskgt", q, k) * scale
     qp, kp = _attn_positions(q_off, k_off, B, S, T)
     m = jnp.ones((max(qp.shape[0], kp.shape[0]), S, T), bool)
     if causal:
@@ -618,15 +619,11 @@ def _flash_bwd_direct(q, k, v, o, lse, do, q_off, k_off, causal, window):
     p = jnp.where(m, jnp.exp(s - lse[..., None]), 0.0)
     do32 = do.astype(f32)
     delta = jnp.sum(do32 * o.astype(f32), axis=-1)             # (B,S,K,G)
-    dv = jnp.einsum("bskgt,bskgh->btkh", p, do32,
-                    preferred_element_type=f32)
-    dp = jnp.einsum("bskgh,btkh->bskgt", do32, v,
-                    preferred_element_type=f32)
+    dv = _einsum32("bskgt,bskgh->btkh", p, do32)
+    dp = _einsum32("bskgh,btkh->bskgt", do32, v)
     ds = p * (dp - delta[..., None]) * scale
-    dq = jnp.einsum("bskgt,btkh->bskgh", ds, k,
-                    preferred_element_type=f32)
-    dk = jnp.einsum("bskgt,bskgh->btkh", ds, q,
-                    preferred_element_type=f32)
+    dq = _einsum32("bskgt,btkh->bskgh", ds, k)
+    dk = _einsum32("bskgt,bskgh->btkh", ds, q)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 

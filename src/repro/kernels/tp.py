@@ -41,7 +41,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops as kops
-from repro.launch.mesh import compat_shard_map
 from repro.perf import autotune
 
 
@@ -109,7 +108,7 @@ def dyad_ff_tp(params, x, *, act: str = "gelu", use_kernel_bwd: bool = True,
         return jax.lax.psum(y, model)
 
     with autotune.tp_shards(tp):
-        y = compat_shard_map(
+        y = jax.shard_map(
             body, mesh=ctx.mesh, in_specs=tuple(in_specs),
             out_specs=P(rows, model if scatter else None),
             check_vma=False)(x2d, *weights)
@@ -158,7 +157,7 @@ def dyad_ff_quant_tp(params, x, *, act: str = "gelu", ctx):
         return jax.lax.psum(y, model)
 
     with autotune.tp_shards(tp):
-        y = compat_shard_map(
+        y = jax.shard_map(
             body, mesh=ctx.mesh, in_specs=tuple(in_specs),
             out_specs=P(rows, model if scatter else None),
             check_vma=False)(x2d, *weights)
@@ -204,7 +203,7 @@ def flash_attention_tp(q, k, v, q_off=0, k_off=0, *, causal: bool = True,
                                     use_kernel_bwd=use_kernel_bwd)
 
     with autotune.tp_shards(tp):
-        return compat_shard_map(
+        return jax.shard_map(
             body, mesh=ctx.mesh,
             in_specs=(q_spec, kv_spec, kv_spec, _off_spec(q_off, rows),
                       _off_spec(k_off, rows)),
@@ -228,7 +227,7 @@ def flash_decode_tp(q, k, v, idx, *, window=None, ctx):
         return kops.flash_decode(qs, ks, vs, i, window=window)
 
     with autotune.tp_shards(tp):
-        return compat_shard_map(
+        return jax.shard_map(
             body, mesh=ctx.mesh,
             in_specs=(q_spec, kv_spec, kv_spec, _off_spec(idx, rows)),
             out_specs=q_spec, check_vma=False)(q, k, v, idx)
@@ -264,7 +263,7 @@ def flash_decode_paged_tp(q, pages_k, pages_v, block_table, idx, *,
                                            scales_v=sv)
 
         with autotune.tp_shards(tp):
-            return compat_shard_map(
+            return jax.shard_map(
                 body, mesh=ctx.mesh,
                 in_specs=(q_spec, pool_spec, pool_spec, P(rows, None),
                           _off_spec(idx, rows), P(None, None, model),
@@ -278,7 +277,7 @@ def flash_decode_paged_tp(q, pages_k, pages_v, block_table, idx, *,
                                        window=window)
 
     with autotune.tp_shards(tp):
-        return compat_shard_map(
+        return jax.shard_map(
             body, mesh=ctx.mesh,
             in_specs=(q_spec, pool_spec, pool_spec, P(rows, None),
                       _off_spec(idx, rows)),

@@ -1,71 +1,38 @@
-"""Production meshes.  Defined as FUNCTIONS so importing this module never
+"""Device meshes.  Defined as FUNCTIONS so importing this module never
 touches jax device state (device count is locked at first jax init).
 
-``compat_make_mesh`` / ``compat_shard_map`` paper over the JAX API drift
-around meshes and shard_map (``jax.sharding.AxisType`` + the ``axis_types=``
-kwarg and ``jax.shard_map``/``check_vma`` only exist in newer releases;
-older ones have plain ``jax.make_mesh`` and
-``jax.experimental.shard_map.shard_map``/``check_rep``) — every mesh the
-repo builds, including the SPMD tests', goes through these shims so tier-1
-stays green across the supported JAX range.
+Every mesh uses ``AxisType.Auto`` axes: shardings are propagated by GSPMD
+from the ``NamedSharding`` annotations the model and the kernel wrappers
+place, not carried in the types.
 """
 from __future__ import annotations
 
+import math
+
 import jax
+from jax.sharding import AxisType
 
 from repro.sharding.rules import MeshRules
 
 
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` across JAX versions.
-
-    Newer JAX: pass ``axis_types=(AxisType.Auto, ...)`` explicitly (Auto is
-    the sharding-in-types default we rely on).  Older JAX: no such kwarg and
-    Auto semantics are implicit — call plain ``make_mesh``; if even that is
-    missing, fall back to ``Mesh`` over a reshaped device grid.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    make = getattr(jax, "make_mesh", None)
-    if make is None:
-        import numpy as np
-
-        n = int(np.prod(shape))
-        devices = np.asarray(jax.devices()[:n]).reshape(shape)
-        return jax.sharding.Mesh(devices, axes)
-    if axis_type is None:
-        return make(shape, axes)
-    return make(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-
-
-def compat_shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across JAX versions.  The replication-check kwarg
-    was renamed ``check_rep`` -> ``check_vma`` independently of shard_map's
-    move out of jax.experimental, so detect the spelling from the signature
-    rather than from where the function lives."""
-    import inspect
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        params = inspect.signature(sm).parameters
-    except (TypeError, ValueError):
-        params = {}
-    kw = {"check_vma" if "check_vma" in params else "check_rep": check_vma}
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+def make_mesh(shape=(2, 2), axes=("data", "model")) -> jax.sharding.Mesh:
+    """A mesh over the first ``prod(shape)`` local devices.  Raises when
+    fewer devices exist (on CPU, force more first with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``)."""
+    n, have = math.prod(shape), len(jax.devices())
+    if have < n:
+        raise ValueError(f"mesh {dict(zip(axes, shape))} needs {n} devices, "
+                         f"{have} visible")
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=jax.devices()[:n])
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_rules(*, multi_pod: bool = False, fsdp: bool = False) -> MeshRules:
     dp = ("pod", "data") if multi_pod else ("data",)
     return MeshRules(model="model", dp=dp, fsdp=("data",) if fsdp else None)
-
-
-def make_test_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh for CPU tests (requires XLA_FLAGS host device count)."""
-    return compat_make_mesh(shape, axes)

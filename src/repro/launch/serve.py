@@ -31,6 +31,7 @@ import jax.numpy as jnp
 
 from repro import configs, faults, obs
 from repro.checkpoint import CheckpointManager
+from repro.launch.cache import enable_compile_cache
 from repro.models import model
 from repro.serve import ContinuousBatchingEngine, Engine
 
@@ -114,6 +115,7 @@ def main():
                          "e.g. --linear dyad_it_4_kernel")
     args = ap.parse_args()
 
+    enable_compile_cache()
     if args.trace:
         obs.enable()
     if args.faults:
@@ -124,9 +126,9 @@ def main():
     # run sits inside one activation-sharding context
     mesh_ctx = contextlib.nullcontext()
     if args.tp > 1 or args.dp > 1:
-        from repro.launch.mesh import make_test_mesh
+        from repro.launch.mesh import make_mesh
         from repro.sharding import ctx as shard_ctx
-        mesh = make_test_mesh((args.dp, args.tp))
+        mesh = make_mesh((args.dp, args.tp))
         mesh_ctx = shard_ctx.activation_sharding(mesh, dp=("data",),
                                                  model="model")
         print(f"[serve] mesh: data={args.dp} model={args.tp}")
@@ -185,8 +187,11 @@ def _run(args):
         if engine.paged:
             print(f"[serve] paged: {engine.stats}")
         if faults.active():
-            print(f"[serve] faults: {faults.snapshot()} "
-                  f"demoted={engine.demoted}")
+            print(f"[serve] faults: {faults.snapshot()}")
+        if engine.demoted:
+            # the NaN guard moved the engine onto weaker kernel routes:
+            # the run finished, but not on the routes it was asked for
+            print(f"[serve] WARNING: demoted kernel routes: {engine.demoted}")
         print({u: results[u][:8] for u in uids[:4]})
         print(f"[serve] summary: {engine.format_summary()}")
         _finish(args, engine.metrics)

@@ -20,6 +20,7 @@ import jax
 
 from repro import configs, faults, obs
 from repro.data import SyntheticLM
+from repro.launch.cache import enable_compile_cache
 from repro.optim import AdamW, Compressor, schedule
 from repro.train import Trainer, init_train_state, make_train_step
 
@@ -61,6 +62,7 @@ def main():
                          "--linear dyad_it_4_kernel")
     args = ap.parse_args()
 
+    enable_compile_cache()
     if args.trace:
         obs.enable()
     if args.faults:

@@ -28,6 +28,7 @@ import contextlib
 import contextvars
 import json
 import os
+import sys
 import time
 import warnings
 from typing import Dict, Iterable, List, Optional, Tuple
@@ -222,6 +223,12 @@ def memo_counts() -> Dict[str, int]:
     """Copy of the get_tuned_blocks memo hit/miss counters (observability +
     tests; counters survive _memo_clear so rates stay meaningful)."""
     return dict(_MEMO_COUNTS)
+
+
+def resolved_blocks() -> Dict[str, Blocks]:
+    """The tiles resolved since the memo was last cleared, by tune key:
+    what the kernels traced in this process actually asked for."""
+    return {k: dict(v) for k, v in _MEMO.items()}
 
 
 def get_cache() -> BlockCache:
@@ -730,11 +737,12 @@ def _time_candidates(kernel, cands: List[Blocks], key: str, iters: int,
                     us = _time_us(lambda c=cand: kernel(**c),
                                   iters=iters, warmup=warmup)
                 except Exception as e:   # invalid tiling for backend/shape
-                    warnings.warn(f"repro.perf: candidate {cand} failed for "
-                                  f"{key}: {e}")
-                    if chatty:
-                        print(f"[autotune] {key}: {i + 1}/{n} {cand} FAILED "
-                              f"({type(e).__name__})", flush=True)
+                    # skipped, never silently: a candidate the compiler
+                    # refuses (too much VMEM, an illegal block) is printed
+                    # every time, verbose or not
+                    print(f"[autotune] {key}: {i + 1}/{n} {cand} FAILED "
+                          f"({type(e).__name__}: {str(e)[:300]})",
+                          file=sys.stderr, flush=True)
                     continue
                 sp.set(us=round(us, 2))
             if chatty:

@@ -34,16 +34,15 @@ from __future__ import annotations
 import os
 from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
 _EPS = 1e-12
 
-# dtype name -> (jnp dtype attr name, symmetric max representable value)
+# dtype name -> (jnp dtype, symmetric max representable value)
 _QDTYPES = {
-    "int8": ("int8", 127.0),
-    "fp8": ("float8_e4m3fn", 448.0),
-    "float8_e4m3fn": ("float8_e4m3fn", 448.0),
+    "int8": (jnp.int8, 127.0),
+    "fp8": (jnp.float8_e4m3fn, 448.0),
+    "float8_e4m3fn": (jnp.float8_e4m3fn, 448.0),
 }
 
 
@@ -53,23 +52,13 @@ def enabled() -> bool:
     return os.environ.get("REPRO_KERNEL_QUANT", "").lower() != "off"
 
 
-def supports_fp8() -> bool:
-    """Does this jax build ship ``float8_e4m3fn``?  (All pinned versions
-    do; guarded so older interpreters degrade to int8 with a clear error
-    instead of an AttributeError mid-trace.)"""
-    return hasattr(jnp, "float8_e4m3fn")
-
-
 def resolve_dtype(name: str) -> Tuple[jnp.dtype, float]:
     """``(jnp dtype, qmax)`` for a quantization dtype name."""
     if name not in _QDTYPES:
         raise ValueError(f"unknown quantization dtype {name!r} "
                          f"(know {sorted(_QDTYPES)})")
-    attr, qmax = _QDTYPES[name]
-    if not hasattr(jnp, attr):
-        raise ValueError(f"backend lacks {attr} (jax {jax.__version__}); "
-                         f"use dtype='int8'")
-    return jnp.dtype(getattr(jnp, attr)), qmax
+    dtype, qmax = _QDTYPES[name]
+    return jnp.dtype(dtype), qmax
 
 
 def quant_symmetric(g, axis=None, dtype: str = "int8"):

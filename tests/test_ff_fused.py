@@ -92,15 +92,16 @@ def test_megakernel_dtypes(act, dtype, tol):
 
 def test_megakernel_tiling_invariance():
     """Result must not depend on the tile choice (sweeps j and k blocks,
-    the two axes the megakernel sequences)."""
-    ws = _ff_weights(2, 32, 64, 24)
-    x = jax.random.normal(jax.random.PRNGKey(1), (16, 64))
+    the two axes the megakernel sequences).  The hidden and input widths
+    span two lane tiles so each has two legal tilings."""
+    ws = _ff_weights(2, 256, 256, 128)
+    x = jax.random.normal(jax.random.PRNGKey(1), (16, 512))
     x1, x2 = ref.block_views(x, 2, "it")
     args = (x1, x2, ws["wu1"], ws["wu2"], ws["wd1"], ws["wd2"])
     base = ref.combine(*dyad_ff_fused(*args, act="gelu", interpret=True),
                        "ot")
-    for bb, bo, bj, bk in [(4, 8, 8, 8), (16, 24, 64, 32), (8, 12, 16, 16),
-                           (16, 24, 32, 8)]:
+    for bb, bo, bj, bk in [(8, 128, 128, 128), (16, 128, 256, 256),
+                           (8, 128, 128, 256), (16, 128, 256, 128)]:
         out = ref.combine(*dyad_ff_fused(
             *args, act="gelu", block_b=bb, block_o=bo, block_j=bj,
             block_k=bk, interpret=True), "ot")
@@ -129,9 +130,17 @@ def test_fused_vs_split_route(act, monkeypatch):
                                rtol=2e-5, atol=2e-5)
 
 
+# bf16 gradients: the kernel route and the einsum oracle each round about
+# six intermediates to bf16 at different points (pre-activation, hidden,
+# output cotangent, dh, du, dx); each rounding moves a value by at most the
+# bf16 unit roundoff u = 2**-8 relative to the reference scale, so the two
+# routes may differ by up to 2 * 6 * u (about 4.7e-2) of that scale.
+_BF16_FF_GRAD_TOL = 2 * 6 * 2.0 ** -8
+
+
 @pytest.mark.parametrize("act", ["gelu", "relu", "swiglu"])
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-4),
-                                       (jnp.bfloat16, 5e-2)])
+                                       (jnp.bfloat16, _BF16_FF_GRAD_TOL)])
 def test_ff_bwd_matches_einsum_oracle(act, dtype, tol):
     """Default backward route (compiled direct-layout XLA off-TPU) vs
     autodiff of the einsum oracle."""
@@ -287,12 +296,16 @@ def test_linear_cfg_spec_token():
 
 def test_plan_ff_tiles_never_degenerate():
     plan = plan_ff_tiles(521, 1031, 769, 1031, 256, 256, 512, 512)
-    assert plan.bB >= 8 and plan.bO >= 128 and plan.bJ >= 128
-    assert plan.bK >= 128
-    for dim, tile in [(plan.padded_b, plan.bB), (plan.padded_o, plan.bO),
-                      (plan.padded_j, plan.bJ), (plan.padded_k, plan.bK)]:
-        assert dim % tile == 0
-    assert plan.grid_steps <= 128
+    # Mosaic-legal tiles (multiples of 8 sublanes / 128 lanes), padding
+    # short of one unit, and a grid of tiles, not of elements
+    for dim, padded, tile, unit in [(521, plan.padded_b, plan.bB, 8),
+                                    (1031, plan.padded_o, plan.bO, 128),
+                                    (769, plan.padded_j, plan.bJ, 128),
+                                    (1031, plan.padded_k, plan.bK, 128)]:
+        assert tile % unit == 0 and padded % tile == 0
+        assert 0 <= padded - dim < unit
+    assert (plan.bB, plan.bO, plan.bJ, plan.bK) == (176, 128, 128, 384)
+    assert plan.grid_steps == 3 * 9 * 7 * 3
     # healthy dims are untouched
     plan = plan_ff_tiles(64, 192, 768, 192, 256, 256, 512, 512)
     assert (plan.padded_b, plan.padded_o, plan.padded_j,

@@ -13,6 +13,28 @@ from repro.kernels.dyad_mm import (dyad_mm_blocks, dyad_mm_blocks_two,
 
 KEY = jax.random.PRNGKey(0)
 
+
+def _assert_within_dot_bound(got, want, pairs):
+    """Elementwise ``|got - want| <= 2 * gamma_n * sum |a_i * b_i|``.
+
+    Summing ``n`` fp32 products in ANY order errs by at most
+    ``gamma_n = n*u / (1 - n*u)`` (u = 2**-24, the fp32 unit roundoff)
+    times the sum of the products' magnitudes (Higham, "Accuracy and
+    Stability of Numerical Algorithms", sec. 3.1).  The kernel and the
+    einsum reference each sum in their own order, hence the factor 2.
+    ``pairs`` lists the ``(einsum spec, a, b)`` contractions ``want`` adds
+    up; ``n`` is their total contraction length.  A padding or indexing
+    bug errs by O(|value|), far above this bound."""
+    u = np.finfo(np.float32).eps / 2
+    n = sum(np.asarray(a).shape[-1] for _, a, _ in pairs)
+    gamma = n * u / (1 - n * u)
+    mag = sum(np.einsum(spec, np.abs(np.asarray(a, np.float64)),
+                        np.abs(np.asarray(b, np.float64)))
+              for spec, a, b in pairs)
+    err = np.abs(np.asarray(got, np.float64) - np.asarray(want, np.float64))
+    excess = err - 2 * gamma * mag
+    assert np.all(excess <= 0), f"max excess over bound {excess.max():.3g}"
+
 SHAPES = [
     # (f_in, f_out, n_dyad, batch)
     (16, 16, 4, 8),
@@ -65,21 +87,24 @@ def test_kernel_gradients(variant):
 
 
 def test_kernel_block_tilings():
-    """Sweep BlockSpec tilings: result must be invariant to tiling choice."""
-    x1 = jax.random.normal(KEY, (16, 4, 32))
-    x2 = jax.random.normal(jax.random.PRNGKey(1), (16, 4, 32))
-    w1 = jax.random.normal(jax.random.PRNGKey(2), (4, 24, 32))
-    w2 = jax.random.normal(jax.random.PRNGKey(3), (4, 24, 32))
+    """Sweep BlockSpec tilings: result must be invariant to tiling choice.
+    Feature dims span two lane tiles so every legal tiling (multiples of
+    8 rows / 128 lanes) of each axis is exercised."""
+    x1 = jax.random.normal(KEY, (16, 4, 256))
+    x2 = jax.random.normal(jax.random.PRNGKey(1), (16, 4, 256))
+    w1 = jax.random.normal(jax.random.PRNGKey(2), (4, 256, 256))
+    w2 = jax.random.normal(jax.random.PRNGKey(3), (4, 256, 256))
+    # tilings differ only in fp32 summation order over k
+    pairs = [("bgk,gok->bgo", x1, w1), ("bgk,gok->bgo", x2, w2)]
     base = dyad_mm_blocks(x1, x2, w1, w2, interpret=True)
-    for bb, bo, bk in [(4, 8, 8), (16, 24, 32), (8, 12, 16), (2, 6, 4)]:
+    for bb, bo, bk in [(8, 128, 128), (16, 256, 256), (8, 128, 256),
+                       (16, 128, 128)]:
         out = dyad_mm_blocks(x1, x2, w1, w2, block_b=bb, block_o=bo,
                              block_k=bk, interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(base),
-                                   rtol=1e-5, atol=1e-5)
-    z1, z2 = dyad_mm_blocks_two(x1, x2, w1, w2, block_b=8, block_o=12,
-                                block_k=16, interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(z1 + z2), np.asarray(base), rtol=1e-5, atol=1e-5)
+        _assert_within_dot_bound(out, base, pairs)
+    z1, z2 = dyad_mm_blocks_two(x1, x2, w1, w2, block_b=8, block_o=128,
+                                block_k=128, interpret=True)
+    _assert_within_dot_bound(z1 + z2, base, pairs)
 
 
 @pytest.mark.parametrize("B,n,d_in,d_out", [
@@ -95,29 +120,36 @@ def test_kernel_degenerate_dims_exact(B, n, d_in, d_out):
     x2 = jax.random.normal(jax.random.PRNGKey(1), (B, n, d_in))
     w1 = jax.random.normal(jax.random.PRNGKey(2), (n, d_out, d_in))
     w2 = jax.random.normal(jax.random.PRNGKey(3), (n, d_out, d_in))
-    want = (jnp.einsum("bgk,gok->bgo", x1, w1)
-            + jnp.einsum("bgk,gok->bgo", x2, w2))
+    pairs = [("bgk,gok->bgo", x1, w1), ("bgk,gok->bgo", x2, w2)]
+    want = sum(jnp.einsum(s, a, b) for s, a, b in pairs)
     got = dyad_mm_blocks(x1, x2, w1, w2, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
+    _assert_within_dot_bound(got, want, pairs)
     z1, z2 = dyad_mm_blocks_two(x1, x2, w1, w2, interpret=True)
-    np.testing.assert_allclose(np.asarray(z1 + z2), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
+    _assert_within_dot_bound(z1 + z2, want, pairs)
 
 
 def test_plan_tiles_never_degenerate():
     """Tiles stay at lane/sublane granularity even for prime dims > block,
     and the grid never explodes to per-element steps."""
     plan = plan_tiles(521, 1031, 1031, 256, 256, 512)   # all prime
-    assert plan.bB >= 8 and plan.bO >= 128 and plan.bK >= 128
-    assert plan.padded_b % plan.bB == 0
-    assert plan.padded_o % plan.bO == 0
-    assert plan.padded_k % plan.bK == 0
-    assert plan.grid_steps <= 64
-    # healthy dims are untouched: no padding, exact divisors
+    # Mosaic-legal tiles (multiples of 8 sublanes / 128 lanes), padding
+    # short of one unit, and a grid of tiles, not of elements
+    for dim, padded, tile, unit in [(521, plan.padded_b, plan.bB, 8),
+                                    (1031, plan.padded_o, plan.bO, 128),
+                                    (1031, plan.padded_k, plan.bK, 128)]:
+        assert tile % unit == 0 and padded % tile == 0
+        assert 0 <= padded - dim < unit
+    assert (plan.bB, plan.bO, plan.bK) == (176, 128, 384)
+    assert plan.grid_steps == 3 * 9 * 3
+    # healthy dims are untouched: no padding, exact divisors; a lane tile
+    # is a multiple of 128 (192 would divide 384 but Mosaic refuses it)
     plan = plan_tiles(64, 384, 512, 256, 256, 512)
     assert (plan.padded_b, plan.padded_o, plan.padded_k) == (64, 384, 512)
-    assert (plan.bB, plan.bO, plan.bK) == (64, 192, 512)
+    assert (plan.bB, plan.bO, plan.bK) == (64, 128, 512)
+    # an axis within its block is one whole-axis tile, legal at any size
+    plan = plan_tiles(13, 192, 129, 256, 256, 512)
+    assert (plan.bB, plan.bO, plan.bK) == (13, 192, 129)
+    assert (plan.padded_b, plan.padded_o, plan.padded_k) == (13, 192, 129)
 
 
 def test_kernel_multi_dim_leading():
@@ -151,14 +183,12 @@ def test_dgrad_kernels_match_einsum(B, n, d_in, d_out):
     z2 = jax.random.normal(jax.random.PRNGKey(1), (B, n, d_out))
     w1 = jax.random.normal(jax.random.PRNGKey(2), (n, d_out, d_in))
     w2 = jax.random.normal(jax.random.PRNGKey(3), (n, d_out, d_in))
-    want = (jnp.einsum("bgo,goi->bgi", z1, w1)
-            + jnp.einsum("bgo,goi->bgi", z2, w2))
+    pairs = [("bgo,goi->bgi", z1, w1), ("bgo,goi->bgi", z2, w2)]
+    want = sum(jnp.einsum(s, a, b) for s, a, b in pairs)
     got = dyad_mm_dgrad(z1, z2, w1, w2, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
+    _assert_within_dot_bound(got, want, pairs)
     d1, d2 = dyad_mm_dgrad_two(z1, z2, w1, w2, interpret=True)
-    np.testing.assert_allclose(np.asarray(d1 + d2), np.asarray(want),
-                               rtol=1e-5, atol=1e-5)
+    _assert_within_dot_bound(d1 + d2, want, pairs)
 
 
 @pytest.mark.parametrize("B,n,d_in,d_out", BWD_SHAPES)

@@ -336,6 +336,23 @@ def test_get_tuned_blocks_memo_invalidated_by_put(cache):
     assert get_tuned_blocks("dyad_mm_blocks", 8, 2, 64, 64) == tuned
 
 
+def test_resolved_blocks_reports_each_key(cache):
+    """resolved_blocks() lists the tiles each traced key resolved to, as
+    copies, and forgets them when the memo is cleared."""
+    assert autotune.resolved_blocks() == {}
+    tuned = {"block_b": 8, "block_o": 64, "block_k": 64}
+    cache.put(tune_key("dyad_mm_blocks", 8, 2, 64, 64), tuned, us=1.0)
+    get_tuned_blocks("dyad_mm_blocks", 8, 2, 64, 64)
+    get_tuned_blocks("dyad_mm_wgrad", 8, 2, 64, 64)
+    got = autotune.resolved_blocks()
+    assert got == {tune_key("dyad_mm_blocks", 8, 2, 64, 64): tuned,
+                   tune_key("dyad_mm_wgrad", 8, 2, 64, 64): DEFAULT_BLOCKS}
+    got[tune_key("dyad_mm_blocks", 8, 2, 64, 64)]["block_b"] = -1
+    assert get_tuned_blocks("dyad_mm_blocks", 8, 2, 64, 64) == tuned
+    cache.invalidate()
+    assert autotune.resolved_blocks() == {}
+
+
 # -- autotune: ff megakernel op keys ------------------------------------------
 
 

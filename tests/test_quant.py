@@ -23,7 +23,7 @@ from repro.serve import ContinuousBatchingEngine
 
 KEY = jax.random.PRNGKey(0)
 
-QDTYPES = ["int8"] + (["fp8"] if quant.supports_fp8() else [])
+QDTYPES = ["int8", "fp8"]
 
 
 def _w(i, shape, dtype=jnp.float32):

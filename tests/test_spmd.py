@@ -42,8 +42,8 @@ step = make_train_step(cfg, opt)
 
 ref_state, ref_m = jax.jit(step)(state, batch)
 
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 rules = MeshRules(model="model", dp=("data",), fsdp=("data",))
 st_sh = state_shardings(mesh, jax.eval_shape(lambda: state), rules)
 b_sh = batch_shardings(mesh, jax.eval_shape(lambda: batch), rules)
@@ -65,9 +65,9 @@ def test_compressed_psum_shard_map():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.optim.compress import compressed_psum
-from repro.launch.mesh import compat_make_mesh, compat_shard_map
+from repro.launch.mesh import make_mesh
 
-mesh = compat_make_mesh((8,), ("dp",))
+mesh = make_mesh((8,), ("dp",))
 x = jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4) / 7.0
 
 def f(xs):
@@ -75,8 +75,8 @@ def f(xs):
 
 # check_vma=False: the all-gather+sum result is replicated by construction
 # but the varying-axes checker cannot infer that through the int8 round-trip
-y = jax.jit(compat_shard_map(f, mesh=mesh, in_specs=P("dp"), out_specs=P(),
-                             check_vma=False))(x)
+y = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("dp"), out_specs=P(),
+                          check_vma=False))(x)
 expect = np.asarray(x).sum(0)
 np.testing.assert_allclose(np.asarray(y), expect, rtol=0.02, atol=0.02)
 print("compressed_psum OK")
@@ -94,7 +94,7 @@ import os
 os.environ["REPRO_KERNEL_FF"] = "fused"
 import jax, jax.numpy as jnp, numpy as np
 from repro import configs, obs
-from repro.launch.mesh import make_test_mesh
+from repro.launch.mesh import make_mesh
 from repro.layers import mlp
 from repro.sharding import ctx as shard_ctx
 
@@ -110,7 +110,7 @@ ref = jax.jit(lambda p, x: mlp.apply_mlp(p, x, lin, act="swiglu"))(params, x)
 g_ref = jax.jit(jax.grad(loss))(params, x)
 
 for shape in ((4, 2), (2, 4)):          # dp x tp: tp=2 and tp=4
-    mesh = make_test_mesh(shape)
+    mesh = make_mesh(shape)
     with shard_ctx.activation_sharding(mesh, dp=("data",), model="model"):
         obs.reset_route_counts()
         out = jax.jit(lambda p, x: mlp.apply_mlp(p, x, lin,
@@ -149,7 +149,7 @@ def test_tp_flash_kernels_match_single_device():
     single-device kernels: prefill fwd+grad, ring decode, paged decode."""
     out = _run("""
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import make_test_mesh
+from repro.launch.mesh import make_mesh
 from repro.sharding import ctx as shard_ctx
 from repro.kernels import ops as kops, tp as ktp
 
@@ -159,7 +159,7 @@ q = jax.random.normal(key, (B, S, K, G, h))
 k = jax.random.normal(jax.random.fold_in(key, 1), (B, T, K, h))
 v = jax.random.normal(jax.random.fold_in(key, 2), (B, T, K, h))
 
-mesh = make_test_mesh((2, 4))
+mesh = make_mesh((2, 4))
 ref = jax.jit(lambda q, k, v: kops.flash_attention(q, k, v, 0, 0))(q, k, v)
 gref = jax.jit(jax.grad(
     lambda q, k, v: jnp.sum(kops.flash_attention(q, k, v, 0, 0) ** 2),
@@ -215,7 +215,7 @@ os.environ["REPRO_KERNEL_FF"] = "fused"
 os.environ["REPRO_KERNEL_ATTN"] = "flash"
 import jax, jax.numpy as jnp, numpy as np
 from repro import configs, obs
-from repro.launch.mesh import make_test_mesh
+from repro.launch.mesh import make_mesh
 from repro.serve import Engine
 from repro.sharding import ctx as shard_ctx
 
@@ -227,7 +227,7 @@ from repro.models import model
 params = model.init_params(cfg, key)
 prompts = jax.random.randint(jax.random.fold_in(key, 1), (4, 8), 0, 256)
 
-mesh = make_test_mesh((2, 2))   # dp=2 x tp=2 (kv heads = 2 divide)
+mesh = make_mesh((2, 2))   # dp=2 x tp=2 (kv heads = 2 divide)
 with shard_ctx.activation_sharding(mesh, dp=("data",), model="model"):
     obs.reset_route_counts()
     eng = Engine(cfg, params, max_len=16)
@@ -258,11 +258,11 @@ def test_tp_paged_pool_shardings():
     out = _run("""
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.launch.mesh import make_test_mesh
+from repro.launch.mesh import make_mesh
 from repro.sharding import MeshRules
 from repro.sharding.rules import cache_shardings
 
-mesh = make_test_mesh((2, 4))
+mesh = make_mesh((2, 4))
 rules = MeshRules(model="model", dp=("data",))
 specs = {
     "pages_k": jax.ShapeDtypeStruct((2, 18, 8, 4, 16), jnp.float32),
