@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the device."""
+
+
+def read(rec):
+    red = rec.get("reduced")
+    if rec["kind"] != "serve" or red is None:
+        return None
+    return 100.0 * red.idle_share
