@@ -1,0 +1,9 @@
+"""Device milliseconds per train step under the program's ``head`` scope (the
+unembedding, logsumexp and the gold-logit gather), forward and backward, in
+the train-step executions wholly inside the traced window
+(chipbench/scopes.py)."""
+from chipbench import scopes
+
+
+def read(rec):
+    return scopes.ms(rec, "head")

@@ -1,0 +1,8 @@
+"""Device milliseconds per train step under the program's ``norm`` scope (the
+layer norms), forward and backward, in the train-step executions wholly
+inside the traced window (chipbench/scopes.py)."""
+from chipbench import scopes
+
+
+def read(rec):
+    return scopes.ms(rec, "norm")

@@ -187,6 +187,12 @@ def _compiler_params(n_parallel: int, n_arbitrary: int):
 # layer-natural names in the autotune ``blocks`` dict: ``block_o`` tiles
 # the produced feature axis (d_in there), ``block_k`` the contracted one.
 
+# A device trace names a Pallas call after the innermost name scope around
+# it: its ``name=`` if it has one, else the jitted wrapper that holds it.
+# The benchmark's readers (chipbench/metrics/) find these kernels by their
+# wrappers (``_mm_impl``, ``_wgrad_impl``, ``_ff_impl``), so the calls carry
+# no ``name=`` and the wrappers keep their names (tests/test_kernel_names.py).
+
 _FWD_DN = (((1,), (1,)), ((), ()))      # (bB, bK) x (bO, bK) -> (bB, bO)
 _DGRAD_DN = (((1,), (0,)), ((), ()))    # (bB, bK) x (bK, bO) -> (bB, bO)
 

@@ -70,6 +70,12 @@ _UNIT_K = 128
 # value; 128 matches the fp32 native tile so no partial-lane relayouts)
 _STATE_LANES = 128
 
+# A device trace names a Pallas call after the innermost name scope around
+# it: its ``name=`` if it has one, else the jitted wrapper that holds it.
+# The benchmark's readers (chipbench/metrics/) find the prefill, dq, dkv and
+# paged decode kernels by their wrappers, so those calls carry no ``name=``;
+# the other decode kernels carry a fixed one (tests/test_kernel_names.py).
+
 
 def resolve_attn_blocks(op: str, rows: int, n_kv: int, h: int, kv_len: int,
                         dtype, g: int, block_q=None, block_k=None,
@@ -660,6 +666,7 @@ def _decode_impl(q, k, v, idx, *, bT, l_real, window, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_decode",
     )(idx, q, k, v)[0]
 
 
@@ -911,6 +918,7 @@ def _decode_paged_q_impl(q, k, v, sk, sv, idx, bt, *, bT, l_real, window,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_decode_paged_q",
     )(idx, bt, q, k, v, sk, sv)[0]
 
 

@@ -27,9 +27,10 @@ def _init_norm(cfg: ModelCfg, dtype):
 
 
 def _apply_norm(cfg: ModelCfg, p, x):
-    if cfg.norm == "layernorm":
-        return norms.layernorm(p, x)
-    return norms.rmsnorm(p, x)
+    with jax.named_scope("norm"):
+        if cfg.norm == "layernorm":
+            return norms.layernorm(p, x)
+        return norms.rmsnorm(p, x)
 
 
 def init_block(key, cfg: ModelCfg, kind: str):
@@ -116,9 +117,10 @@ def apply_block(
     x = shard_ctx.constrain_residual(x)
     h = _apply_norm(cfg, params["norm1"], x)
     if kind == "hybrid":
-        a, kv = _self_attn(params["attn"], cfg, h, causal=True,
-                           cache=cache.get("kv") if cache else None,
-                           positions=positions)
+        with jax.named_scope("attn"):
+            a, kv = _self_attn(params["attn"], cfg, h, causal=True,
+                               cache=cache.get("kv") if cache else None,
+                               positions=positions)
         if cache is not None:
             s, sc = _ssm_with_cache(params["ssm"], cfg, h, cache["ssm"],
                                     prefill)
@@ -143,36 +145,42 @@ def apply_block(
                 chunk=cfg.ssd_chunk)
         return x + s, new_cache, aux
     else:
-        a, kv = _self_attn(params["attn"], cfg, h, causal=causal,
-                           cache=cache.get("kv") if cache else None,
-                           positions=positions)
+        # each sublayer's scope also holds its residual add, so that a
+        # fusion XLA roots at that add is named for the sublayer
+        with jax.named_scope("attn"):
+            a, kv = _self_attn(params["attn"], cfg, h, causal=causal,
+                               cache=cache.get("kv") if cache else None,
+                               positions=positions)
+            x = x + a
         if cache is not None:
             new_cache["kv"] = kv
-        x = x + a
 
     if kind == "dec_cross":
         h = _apply_norm(cfg, params["xnorm"], x)
-        if cache is not None and "xk" in cache:
-            # cross K/V precomputed at prefill; attend directly.
-            xa = _cross_from_cache(params["xattn"], cfg, h, cache)
-            new_cache["xk"], new_cache["xv"] = cache["xk"], cache["xv"]
-        else:
-            xa, _ = attn_lib.attention(
-                params["xattn"], h, n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
-                head_dim=cfg.hd, lin_cfg=cfg.linear, rope_theta=None,
-                positions=jnp.arange(h.shape[1]), causal=False,
-                kv_input=enc_out)
-        x = x + xa
+        with jax.named_scope("attn"):
+            if cache is not None and "xk" in cache:
+                # cross K/V precomputed at prefill; attend directly.
+                xa = _cross_from_cache(params["xattn"], cfg, h, cache)
+                new_cache["xk"], new_cache["xv"] = cache["xk"], cache["xv"]
+            else:
+                xa, _ = attn_lib.attention(
+                    params["xattn"], h, n_heads=cfg.n_heads,
+                    n_kv=cfg.n_kv_heads, head_dim=cfg.hd,
+                    lin_cfg=cfg.linear, rope_theta=None,
+                    positions=jnp.arange(h.shape[1]), causal=False,
+                    kv_input=enc_out)
+            x = x + xa
 
     h = _apply_norm(cfg, params["norm2"], x)
-    if kind == "moe":
-        m, aux = moe_lib.apply_moe(
-            params["moe"], h, cfg.linear, n_experts=cfg.n_experts,
-            top_k=cfg.top_k, capacity_factor=cfg.capacity_factor, act=cfg.act,
-            chunk=cfg.moe_chunk)
-    else:
-        m = mlp_lib.apply_mlp(params["mlp"], h, cfg.linear, act=cfg.act)
-    return x + m, new_cache, aux
+    with jax.named_scope("ff"):
+        if kind == "moe":
+            m, aux = moe_lib.apply_moe(
+                params["moe"], h, cfg.linear, n_experts=cfg.n_experts,
+                top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
+                act=cfg.act, chunk=cfg.moe_chunk)
+        else:
+            m = mlp_lib.apply_mlp(params["mlp"], h, cfg.linear, act=cfg.act)
+        return x + m, new_cache, aux
 
 
 def _cross_from_cache(p, cfg: ModelCfg, q_in, cache):

@@ -2,6 +2,12 @@
 
 Layer params are stacked on a leading ``n_layers`` axis and the stack is
 ``jax.lax.scan``-ed (with optional remat) — HLO size stays O(1) in depth.
+
+Each layer's ops sit under one ``jax.named_scope`` — ``embed``, ``norm``,
+``attn``, ``ff``, ``head`` (here and in ``blocks``) and ``optimizer``
+(``train.step``) — which lands in every HLO instruction's ``op_name``,
+backward and remat ops included, so a device trace splits by layer.  The
+scopes are metadata only: they add no op.
 """
 from __future__ import annotations
 
@@ -67,9 +73,15 @@ def init_params(cfg: ModelCfg, key) -> dict:
 
 
 def _final_norm(cfg, p, x):
-    if cfg.norm == "layernorm":
-        return norms.layernorm(p, x)
-    return norms.rmsnorm(p, x)
+    with jax.named_scope("norm"):
+        if cfg.norm == "layernorm":
+            return norms.layernorm(p, x)
+        return norms.rmsnorm(p, x)
+
+
+def _unembed(params, x):
+    with jax.named_scope("head"):
+        return embed_lib.unembed(params.get("head", params["embed"]), x)
 
 
 def _run_stack(cfg: ModelCfg, stacked, x, kind: str, *, enc_out=None,
@@ -105,22 +117,25 @@ def encode(cfg: ModelCfg, params, frames):
 
 
 def _embed_inputs(cfg: ModelCfg, params, batch, offset=0):
-    tokens = batch["tokens"]
-    x = embed_lib.embed(params["embed"], tokens, iota=cfg.iota_embed)
-    if cfg.family == "vlm" and "patches" in batch:
-        pe = frontend_lib.apply_frontend(params["frontend"], batch["patches"],
-                                         add_positions=False)
-        x = jnp.concatenate([pe.astype(x.dtype), x], axis=1)
-    S = x.shape[1]
-    # offset may be a scalar (homogeneous batch) or a (B,) vector of per-slot
-    # positions (continuous batching) -> positions (S,) or (B, S).
-    if getattr(offset, "ndim", 0) == 1:
-        positions = offset[:, None] + jnp.arange(S)
-    else:
-        positions = offset + jnp.arange(S)
-    if cfg.pos_embed == "learned":
-        x = x + embed_lib.embed(params["pos"], positions)  # pos table stays gathered
-    return x.astype(cfg.cdtype), positions
+    with jax.named_scope("embed"):
+        tokens = batch["tokens"]
+        x = embed_lib.embed(params["embed"], tokens, iota=cfg.iota_embed)
+        if cfg.family == "vlm" and "patches" in batch:
+            pe = frontend_lib.apply_frontend(
+                params["frontend"], batch["patches"], add_positions=False)
+            x = jnp.concatenate([pe.astype(x.dtype), x], axis=1)
+        S = x.shape[1]
+        # offset may be a scalar (homogeneous batch) or a (B,) vector of
+        # per-slot positions (continuous batching) -> positions (S,) or
+        # (B, S).
+        if getattr(offset, "ndim", 0) == 1:
+            positions = offset[:, None] + jnp.arange(S)
+        else:
+            positions = offset + jnp.arange(S)
+        if cfg.pos_embed == "learned":
+            # pos table stays gathered
+            x = x + embed_lib.embed(params["pos"], positions)
+        return x.astype(cfg.cdtype), positions
 
 
 def forward(cfg: ModelCfg, params, batch, *, last_only: bool = False):
@@ -140,26 +155,28 @@ def forward(cfg: ModelCfg, params, batch, *, last_only: bool = False):
         x = x[:, batch["patches"].shape[1]:]     # logits over text positions
     if last_only:
         x = x[:, -1:]
-    head = params.get("head", params["embed"])
-    return embed_lib.unembed(head, x), aux
+    return _unembed(params, x), aux
 
 
 def loss_fn(cfg: ModelCfg, params, batch):
     """Next-token cross-entropy (+ router aux).  labels < 0 are masked."""
     logits, aux = forward(cfg, params, batch)
-    labels = batch["labels"]
-    mask = (labels >= 0).astype(jnp.float32)
-    labels = jnp.maximum(labels, 0)
-    # logsumexp - gold_logit form: partitions cleanly over a vocab-sharded
-    # logits axis (no full log_softmax materialization on the bwd pass).
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
-    nll = lse - gold
-    denom = jnp.maximum(mask.sum(), 1.0)
-    loss = (nll * mask).sum() / denom
-    total = loss + cfg.router_aux_coef * aux
-    return total, {"loss": loss, "aux": aux,
-                   "ppl_proxy": jnp.exp(jnp.minimum(loss, 20.0))}
+    with jax.named_scope("head"):
+        labels = batch["labels"]
+        mask = (labels >= 0).astype(jnp.float32)
+        labels = jnp.maximum(labels, 0)
+        # logsumexp - gold_logit form: partitions cleanly over a
+        # vocab-sharded logits axis (no full log_softmax materialization
+        # on the bwd pass).
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, labels[..., None],
+                                   axis=-1)[..., 0]
+        nll = lse - gold
+        denom = jnp.maximum(mask.sum(), 1.0)
+        loss = (nll * mask).sum() / denom
+        total = loss + cfg.router_aux_coef * aux
+        return total, {"loss": loss, "aux": aux,
+                       "ppl_proxy": jnp.exp(jnp.minimum(loss, 20.0))}
 
 
 def init_cache(cfg: ModelCfg, batch: int, max_len: int, dtype=jnp.bfloat16,
@@ -230,8 +247,7 @@ def prefill(cfg: ModelCfg, params, cache, tokens, *, frames=None,
     x = _final_norm(cfg, params["final_norm"], x)
     if last_only:
         x = x[:, -1:]
-    head = params.get("head", params["embed"])
-    return embed_lib.unembed(head, x), new_cache
+    return _unembed(params, x), new_cache
 
 
 def decode_step(cfg: ModelCfg, params, cache, tokens):
@@ -241,8 +257,7 @@ def decode_step(cfg: ModelCfg, params, cache, tokens):
     x, new_cache, _ = _run_stack(cfg, params["layers"], x, block_kind(cfg),
                                  positions=positions, caches=cache)
     x = _final_norm(cfg, params["final_norm"], x)
-    head = params.get("head", params["embed"])
-    return embed_lib.unembed(head, x), new_cache
+    return _unembed(params, x), new_cache
 
 
 def _cache_pos(cfg: ModelCfg, cache):

@@ -24,7 +24,7 @@ from __future__ import annotations
 from typing import Dict, Tuple
 
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
-                               format_serving_line, format_training_line)
+                               format_serving_line)
 from repro.obs.trace import (Tracer, disable, enable, enabled, export,
                              get_tracer, instant, span, verbose)
 
@@ -62,7 +62,7 @@ def routes_snapshot() -> Dict[str, int]:
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "format_serving_line", "format_training_line",
+    "format_serving_line",
     "Tracer", "enable", "disable", "enabled", "export", "get_tracer",
     "instant", "span", "verbose",
     "route_event", "route_counts", "reset_route_counts", "routes_snapshot",

@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import json
 import time
-from typing import Dict, List, Optional, Union
+from typing import Dict, List, Union
 
 Number = Union[int, float]
 
@@ -173,16 +173,3 @@ def format_serving_line(m: MetricsRegistry) -> str:
             f"rejects={c.get('admission_rejects', 0)} "
             f"preempts={c.get('preemptions', 0)} "
             f"faulted={c.get('retired_faulted', 0)}")
-
-
-def format_training_line(m: MetricsRegistry, step: int,
-                         loss: Optional[float] = None,
-                         extra: str = "") -> str:
-    snap = m.snapshot()
-    h = snap["histograms"].get("step_time_s", {})
-    g = snap["gauges"]
-    line = (f"step {step} "
-            + (f"loss={loss:.4f} " if loss is not None else "")
-            + f"tok/s={_fmt(g.get('tokens_per_s', {}).get('value', 0.0))} "
-            f"step_ms p50={_fmt(1e3 * h.get('p50', 0.0))}")
-    return line + (f" {extra}" if extra else "")

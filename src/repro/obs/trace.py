@@ -18,6 +18,11 @@ as ``tid``.  Load the written file in ``ui.perfetto.dev`` or
 
 Span args must be JSON-serializable scalars (the recorder stringifies
 anything else at export, never in the hot path).
+
+While a ``jax.profiler`` session is active, every span also writes a
+``TraceAnnotation`` named ``repro.<name>`` with the span's args, whether
+or not the ring buffer is on, so the program's spans sit on the device
+trace's clock and can name an idle gap of the device.
 """
 from __future__ import annotations
 
@@ -28,7 +33,11 @@ import threading
 import time
 from typing import Deque, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 DEFAULT_CAPACITY = 200_000
+# the name prefix of the spans written into a profiler trace
+PROFILER_PREFIX = "repro."
 
 
 class _NullSpan:
@@ -50,30 +59,44 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One live span: records enter/exit timestamps on the tracer clock."""
+    """One live span: records enter/exit timestamps on the tracer clock
+    (``tracer`` None: the ring buffer is off), and writes a profiler
+    annotation while a profiler session is active."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_annotation")
 
-    def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
+    def __init__(self, tracer: Optional["Tracer"], name: str, cat: str,
+                 args: dict):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._annotation = None
 
     def __enter__(self) -> "_Span":
+        if TraceAnnotation.is_enabled():
+            self._annotation = TraceAnnotation(PROFILER_PREFIX + self.name,
+                                               **self.args)
+            self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter_ns()
-        self._tracer._record(self.name, self.cat, self._t0, t1 - self._t0,
-                             self.args)
+        if self._tracer is not None:
+            self._tracer._record(self.name, self.cat, self._t0,
+                                 t1 - self._t0, self.args)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
         return False
 
     def set(self, **args) -> None:
         """Attach/overwrite args from inside the span body (e.g. a result
         computed mid-span, like the number of tokens a step emitted)."""
         self.args.update(args)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**args)
 
 
 class Tracer:
@@ -187,11 +210,14 @@ def enabled() -> bool:
 
 def span(name: str, cat: str = "repro", **args):
     """``with obs.span("decode_step", batch=4): ...`` — a nestable span on
-    the global tracer, or a shared no-op when tracing is off.  The disabled
-    path is one global load and one branch."""
+    the global tracer and, while a profiler session is active, in the
+    profiler's trace; a shared no-op when neither is on.  The disabled
+    path is one global load, one profiler check and one branch."""
     t = _TRACER
     if t is None:
-        return _NULL_SPAN
+        if not TraceAnnotation.is_enabled():
+            return _NULL_SPAN
+        return _Span(None, name, cat, args)
     return t.span(name, cat, **args)
 
 

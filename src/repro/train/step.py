@@ -95,24 +95,27 @@ def make_train_step(cfg: ModelCfg, opt: AdamW,
             grads = jax.tree.map(lambda g: g * nanify.astype(g.dtype), grads)
             metrics = dict(metrics, loss=metrics["loss"] * nanify)
 
-        new_state = dict(state)
-        if "compress" in state and compressor is not None:
-            grads, new_state["compress"] = compressor.compress_decompress(
-                grads, state["compress"])
-        new_params, new_opt, om = opt.update(grads, state["opt"], params)
-        new_state["params"], new_state["opt"] = new_params, new_opt
-        metrics = dict(metrics, **om)
-        if nan_guard:
-            # skip-step, decided INSIDE the jit: a non-finite loss or grad
-            # norm keeps the old state leaf-for-leaf.  grad_norm is the
-            # cheap single-scalar witness for "any grad is non-finite"
-            # (AdamW already computes it), loss catches forward blowups.
-            ok = jnp.isfinite(metrics["loss"]) & jnp.isfinite(om["grad_norm"])
-            new_state = jax.tree.map(
-                lambda n, o: jnp.where(ok, n, o).astype(n.dtype)
-                if hasattr(n, "dtype") else n,
-                new_state, state)
-            metrics = dict(metrics, nonfinite=(~ok).astype(jnp.float32))
+        with jax.named_scope("optimizer"):
+            new_state = dict(state)
+            if "compress" in state and compressor is not None:
+                grads, new_state["compress"] = compressor.compress_decompress(
+                    grads, state["compress"])
+            new_params, new_opt, om = opt.update(grads, state["opt"], params)
+            new_state["params"], new_state["opt"] = new_params, new_opt
+            metrics = dict(metrics, **om)
+            if nan_guard:
+                # skip-step, decided INSIDE the jit: a non-finite loss or
+                # grad norm keeps the old state leaf-for-leaf.  grad_norm
+                # is the cheap single-scalar witness for "any grad is
+                # non-finite" (AdamW already computes it), loss catches
+                # forward blowups.
+                ok = (jnp.isfinite(metrics["loss"])
+                      & jnp.isfinite(om["grad_norm"]))
+                new_state = jax.tree.map(
+                    lambda n, o: jnp.where(ok, n, o).astype(n.dtype)
+                    if hasattr(n, "dtype") else n,
+                    new_state, state)
+                metrics = dict(metrics, nonfinite=(~ok).astype(jnp.float32))
         return new_state, metrics
 
     return train_step

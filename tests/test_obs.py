@@ -98,6 +98,51 @@ def test_disabled_tracer_is_shared_noop_and_cheap():
     assert dt < 1.0, f"disabled span too slow: {dt:.3f}s / 100k"
 
 
+def _profiled_host_spans(tmp_path, body):
+    """``repro.*`` events on the host planes of a profiler trace of
+    ``body()``, as ``{name: args}``."""
+    import glob
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("repro."):
+                        found[e.name] = dict(e.stats)
+    return found
+
+
+@pytest.mark.parametrize("ring_buffer", [False, True])
+def test_span_lands_on_the_profiler_trace(tmp_path, ring_buffer):
+    """While a profiler session runs, a span also writes a
+    ``TraceAnnotation`` ``repro.<name>`` with its args, the ring buffer on
+    or off; afterwards the disabled path is the shared no-op again."""
+    obs.disable()
+    t = obs.enable() if ring_buffer else None
+
+    def body():
+        with obs.span("decode_step", cat="serve", batch=4) as s:
+            s.set(tokens=7)
+            jnp.ones(3).block_until_ready()
+
+    try:
+        found = _profiled_host_spans(tmp_path, body)
+    finally:
+        obs.disable()
+    assert found["repro.decode_step"] == {"batch": 4, "tokens": 7}
+    if ring_buffer:
+        assert [e["name"] for e in t.to_chrome_trace()["traceEvents"]] == [
+            "decode_step"]
+    assert obs.span("a") is obs.span("b")
+
+
 def test_verbose_gate(tracer, monkeypatch):
     monkeypatch.setenv("REPRO_OBS_VERBOSE", "0")
     assert not obs.verbose()          # explicit off wins over enabled tracer
