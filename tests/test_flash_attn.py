@@ -88,6 +88,108 @@ def test_prefill_offsets():
                                    atol=2e-5)
 
 
+# -- tile classes: empty / interior / edge ------------------------------------
+
+
+@pytest.mark.parametrize("window", [None, 200])
+@pytest.mark.parametrize("G", [1, 2, 4])
+@pytest.mark.parametrize("block_k", [128, 512])
+@pytest.mark.parametrize("block_q", [8, 128, 256])
+def test_tile_class_matches_mask(block_q, block_k, G, window):
+    """The predicate the kernels branch on agrees with the mask they
+    build: a tile is interior exactly when its mask is all true,
+    and an empty (out-of-band) tile's mask is all false — over padded and
+    unpadded S/T, and offsets d on and around the tile boundaries, where a
+    diagonal or window edge passes within one position of a tile corner."""
+    for S, T in [(1024, 1024), (1001, 1000), (512, 1100)]:
+        bQ, Sp, bT, Tp = fa._plan_attn(S, T, block_q, block_k)
+        nq, nt = Sp // bQ, Tp // bT
+        tile = jax.jit(jax.vmap(jax.vmap(
+            lambda d, qi, ki: fa._tile_mask(d, qi, ki, bQ, bT, G, T, True,
+                                            window),
+            (None, None, 0)), (None, 0, None)))
+        qi, ki = np.arange(nq)[:, None], np.arange(nt)[None, :]
+        for d in (-3, -2, -1, 0, 1, 2, 5, 6, 7, 126, 127, 128, 129):
+            masks = tile(jnp.int32(d), jnp.arange(nq), jnp.arange(nt))
+            all_true = np.asarray(masks.all(axis=(2, 3)))
+            any_true = np.asarray(masks.any(axis=(2, 3)))
+            band, interior = fa._tile_class(True, window, d, qi, ki, bQ, bT,
+                                            T)
+            where = f"S={S} T={T} bQ={bQ} bT={bT} d={d}"
+            np.testing.assert_array_equal(
+                np.broadcast_to(interior, (nq, nt)), all_true, err_msg=where)
+            assert not np.any(~np.broadcast_to(band, (nq, nt)) & any_true), \
+                where
+
+
+def test_tile_counts_at_the_training_shape():
+    """OPT-125m training at S = T = 2,048 on the chip's default tiles
+    (bQ 256, bK 512): per (batch, kv-head), 12 empty, 12 interior and 8
+    edge tiles of 32."""
+    bQ, Sp, bT, Tp = fa._plan_attn(2048, 2048, 256, 512)
+    assert fa._tile_counts(Sp // bQ, Tp // bT, bQ, bT, 2048, True,
+                           None) == (12, 12, 8)
+    # unmasked attention is all interior; a padded column makes its tile edge
+    assert fa._tile_counts(4, 2, 128, 128, 256, False, None) == (0, 8, 0)
+    assert fa._tile_counts(4, 2, 128, 128, 250, False, None) == (0, 4, 4)
+
+
+def _ref_lse(q, k, qpos, kpos, window):
+    """Oracle log-sum-exp in the kernel's (B, K, S*G) row layout."""
+    B, S, K, G, h = q.shape
+    s = jnp.einsum("bskgh,btkh->bksgt", q, k) / float(h) ** 0.5
+    valid = kpos[None, :] <= qpos[:, None]
+    if window is not None:
+        valid = valid & (qpos[:, None] - kpos[None, :] < window)
+    s = jnp.where(valid[None, None, :, None, :], s, -jnp.inf)
+    return jax.nn.logsumexp(s, axis=-1).reshape(B, K, S * G)
+
+
+# S = T = 512 on 128 x 128 tiles: 16 tiles per head, of which 6 are
+# interior at d = 0 (fewer at d = 2, more at d = 128; none under the
+# 200-position window, some under 400) — the unmasked dq body runs.
+_MULTI_TILE = [pytest.param(G, offs, window,
+                            id=f"G{G}-q{offs[0]}k{offs[1]}-w{window}")
+               for G in (1, 2) for offs in [(0, 0), (128, 0), (5, 3)]
+               for window in (None, 200, 400)]
+
+
+@pytest.mark.parametrize("G,offs,window", _MULTI_TILE)
+def test_prefill_multi_tile_vs_oracle(G, offs, window):
+    """Forward ``o`` and ``lse`` on a grid with interior, edge and empty
+    tiles, against the einsum oracle."""
+    S = T = 512
+    q, k, v, _ = _rand(1, S, T, 2, G, 16)
+    qpos, kpos = offs[0] + jnp.arange(S), offs[1] + jnp.arange(T)
+    got, lse = fa.flash_prefill(q, k, v, *offs, causal=True, window=window,
+                                save_lse=True, block_q=128, block_k=128,
+                                interpret=True)
+    want = ref.sdpa_ref(q, k, v, qpos, kpos, causal=True, window=window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse),
+                               np.asarray(_ref_lse(q, k, qpos, kpos, window)),
+                               atol=2e-5)
+
+
+@pytest.mark.parametrize("G,offs,window", _MULTI_TILE)
+def test_grads_multi_tile_vs_einsum_vjp(G, offs, window):
+    """``dq`` (forward grid) and ``dk``/``dv`` (transposed grid) on the same
+    multi-tile grids, against autodiff of the einsum oracle."""
+    S = T = 512
+    q, k, v, kk = _rand(1, S, T, 2, G, 16)
+    do = jax.random.normal(kk, q.shape)
+    qpos, kpos = offs[0] + jnp.arange(S), offs[1] + jnp.arange(T)
+    kw = dict(causal=True, window=window, block_q=128, block_k=128,
+              interpret=True)
+    o, lse = fa.flash_prefill(q, k, v, *offs, save_lse=True, **kw)
+    got = fa.flash_prefill_grads(q, k, v, o, lse, do, *offs, **kw)
+    _, vjp = jax.vjp(lambda q, k, v: ref.sdpa_ref(
+        q, k, v, qpos, kpos, causal=True, window=window), q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), got, vjp(do)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
+                                   err_msg=name)
+
+
 def test_fully_masked_rows_are_zero():
     """A row with no valid key yields 0 — the guard the kernels implement
     explicitly and `_naive_sdpa` gained for parity with `_chunked_sdpa`."""
@@ -354,6 +456,39 @@ def test_flash_tiles_resolved_at_trace_time(cache, monkeypatch):
         qd, ckv, ckv)
     assert seen["flash_prefill"] == tuned_p
     assert seen["flash_decode"] == tuned_d
+
+
+def test_flash_tiles_counter(cache, monkeypatch):
+    """Tracing the flash op's backward records one ``flash_tiles`` route
+    event (the dq kernel is the one that splits interior from edge tiles),
+    counting the tile classes of one (batch, kv-head) grid as the predicate
+    does; the forward alone records none."""
+    from repro import obs
+
+    S, K, G, h = 512, 2, 2, 16
+    cache.put(tune_key("flash_prefill", S, K, h, S, d_mid=G),
+              {"block_b": 128, "block_o": 128, "block_k": 128}, us=1.0)
+    seen = []
+    real = obs.route_event
+
+    def spy(op, route, **args):
+        if op == "flash_tiles":
+            seen.append((route, args))
+        return real(op, route, **args)
+
+    monkeypatch.setattr(obs, "route_event", spy)
+    monkeypatch.setenv("REPRO_KERNEL_BWD", "pallas")
+    kops._make_flash_attention.cache_clear()
+    q = jnp.zeros((1, S, K, G, h))
+    kv = jnp.zeros((1, S, K, h))
+    jax.jit(lambda q, k, v: kops.flash_attention(q, k, v)).lower(q, kv, kv)
+    assert seen == []
+    jax.jit(jax.grad(lambda q, k, v: kops.flash_attention(q, k, v).sum())
+            ).lower(q, kv, kv)
+    assert fa._tile_counts(4, 4, 128, 128, S, True, None) == (6, 6, 4)
+    assert seen == [("empty6_interior6_edge4",
+                     dict(empty=6, interior=6, edge=4))]
+    kops._make_flash_attention.cache_clear()
 
 
 def test_autotune_sweeps_flash_ops(cache):
