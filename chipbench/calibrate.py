@@ -62,10 +62,10 @@ def train_readings(cell: dict, seeds: list, control_seeds: list) -> None:
 
 def serve_readings(cell: dict, seeds: list, control_seeds: list,
                    seconds: float, devices) -> None:
-    from chipbench import serve, weights
+    from chipbench import models, serve
 
     conf, wl = cell["config"], cell["workload"]
-    m = weights.dims(conf)
+    m = models.of(conf).dims(conf)
     for seed in seeds:
         args = argparse.Namespace(workload=cell["entry"]["name"], seed=seed,
                                   seconds=seconds, trace=0)

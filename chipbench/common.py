@@ -57,19 +57,15 @@ def cell(name: str) -> dict:
 
 def program_cfg(conf: dict):
     """The program's model configuration for a configuration file, checked
-    against the file's published sizes."""
+    against the sizes its model module reads from the file."""
     from repro import configs
+
+    from chipbench import models
 
     p = conf["program"]
     cfg = configs.get(p["arch"], linear=configs.linear_cfg(p["linear"]),
                       **p["overrides"])
-    heads = conf["num_attention_heads"]
-    want = {"n_layers": conf["num_hidden_layers"],
-            "d_model": conf["hidden_size"], "vocab_size": conf["vocab_size"],
-            "n_heads": heads,
-            "n_kv_heads": conf.get("num_key_value_heads", heads),
-            "hd": conf.get("head_dim", conf["hidden_size"] // heads),
-            "d_ff": conf.get("intermediate_size", conf.get("ffn_dim"))}
+    want = models.of(conf).program_sizes(conf)
     got = {k: getattr(cfg, k) for k in want}
     if got != want:
         raise SystemExit(f"chipbench: the program's {p['arch']} is {got}, "

@@ -1,8 +1,8 @@
 """Share of its roofline that the flash attention kernels reach in the
 training step: the least time of the step's causal attention forward and
-backward (chipbench/work.py), over the device time of the prefill, dq and
-dkv kernels' events, summed over the train-step executions wholly inside
-the traced window."""
+backward (counted by the configuration's model module), over the device
+time of the prefill, dq and dkv kernels' events, summed over the
+train-step executions wholly inside the traced window."""
 from chipbench import work
 
 # the Pallas calls of kernels/flash_attn.py: the trace names each by the
@@ -24,5 +24,6 @@ def read(rec):
     if not steps or device_s <= 0:
         return None
     least = sum(work.least_time(f, b, rec["peaks"]) for f, b in
-                work.train_flash_calls(rec["m"], rec["batch"], rec["seq"]))
+                rec["model"].train_flash_calls(rec["m"], rec["batch"],
+                                                rec["seq"]))
     return 100.0 * least * len(steps) / device_s

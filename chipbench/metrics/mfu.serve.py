@@ -1,6 +1,7 @@
 """Model FLOPs of the work the window completed (each decode token at its
 context, each prompt whose first token came in the window with its whole
-prefill: chipbench/work.py), over the window, over the chip's bf16 peak."""
+prefill, counted by the configuration's model module), over the window,
+over the chip's bf16 peak."""
 from chipbench import serve
 
 

@@ -1,8 +1,8 @@
 """Share of its roofline that the paged decode attention kernel reaches:
 the least time of every live lane's query against its whole cached
 context, per layer, in every engine step wholly inside the traced window
-(chipbench/work.py), over the device time of the kernel's events in those
-steps."""
+(counted by the configuration's model module), over the device time of
+the kernel's events in those steps."""
 from chipbench import work
 
 KERNELS = ("%_decode_paged_impl",)
@@ -25,7 +25,8 @@ def read(rec):
         spans.append((start, start + dur))
         if s["contexts"]:
             least += sum(work.least_time(f, b, rec["peaks"]) for f, b in
-                         work.paged_decode_calls(rec["m"], s["contexts"]))
+                         rec["model"].paged_decode_calls(rec["m"],
+                                                         s["contexts"]))
     device_s = red.op_time_s(match, spans)
     if not spans or device_s <= 0:
         return None

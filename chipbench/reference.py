@@ -1,8 +1,9 @@
-"""The plain reference: the two configurations' forward pass, loss and
-AdamW in straightforward ``jax.numpy``, float32 at the highest matmul
+"""The plain reference's library: the pieces that the model modules'
+forward passes and losses (``chipbench/models/``) are built from, and
+AdamW, in straightforward ``jax.numpy``, float32 at the highest matmul
 precision, written from the published model descriptions and the DYAD
 paper.  It imports nothing of the program and takes nothing the program
-made: it draws its weights itself (:mod:`chipbench.weights`).
+made: the model modules draw the weights themselves.
 
 ``prec="fp8"`` is the control: the same computation with every matmul
 operand rounded to float8_e4m3fn under a per-tensor scale (its largest
@@ -76,7 +77,10 @@ def dyad(p, x, variant: str, prec: str):
 
 
 def dense(p, x, prec: str):
-    return mm("...i,oi->...o", x, p["w"], prec)
+    """``x (..., f_in) -> (..., f_out)`` through a dense ``(f_out, f_in)``
+    matrix ``w``, with its bias ``b`` where it has one."""
+    y = mm("...i,oi->...o", x, p["w"], prec)
+    return y + p["b"] if "b" in p else y
 
 
 def layernorm(p, x, eps):
@@ -99,18 +103,14 @@ def rope(x, pos, theta):
     return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
 
 
-def attention(m, p, h, pos, prec: str, q_block: int):
-    """Causal grouped-query attention of one sequence ``h (S, d)``, in
-    blocks of ``q_block`` queries against the keys up to each block's
-    end (so that the score matrix never exists whole)."""
-    S = h.shape[0]
-    H, K, hd = m["heads"], m["kv_heads"], m["hd"]
-    q = dense(p["wq"], h, prec).reshape(S, H, hd)
-    k = dense(p["wk"], h, prec).reshape(S, K, hd)
-    v = dense(p["wv"], h, prec).reshape(S, K, hd)
-    if not m["opt"]:
-        q = rope(rmsnorm(p["q_norm"], q, m["eps"]), pos, m["rope_theta"])
-        k = rope(rmsnorm(p["k_norm"], k, m["eps"]), pos, m["rope_theta"])
+def causal_attention(q, k, v, prec: str, q_block: int):
+    """Causal grouped-query attention of one sequence: ``q (S, H, hd)``
+    against ``k (S, K, hd)`` and ``v (S, K, dv)`` (query head h reads kv
+    head h // (H / K)), in blocks of ``q_block`` queries against the keys
+    up to each block's end, so that the score matrix never exists whole.
+    Returns ``(S, H * dv)``."""
+    S, H, hd = q.shape
+    K = k.shape[1]
     G = H // K
     q = q.reshape(S, K, G, hd) / np.sqrt(hd)     # head h = kv head h // G
     outs = []
@@ -121,53 +121,7 @@ def attention(m, p, h, pos, prec: str, q_block: int):
         s = jnp.where(causal, s, -jnp.inf)
         w = jax.nn.softmax(s, axis=-1)
         outs.append(mm("kgqt,tkh->qkgh", w, v[:s1], prec))
-    o = jnp.concatenate(outs, 0).reshape(S, H * hd)
-    return dense(p["wo"], o, prec)
-
-
-def ff(m, p, h, prec: str):
-    if m["opt"]:
-        return dyad(p["down"], jax.nn.relu(dyad(p["up"], h, "it", prec)),
-                    "it", prec)
-    g = dyad(p["gate"], h, "it", prec)
-    u = dyad(p["up"], h, "it", prec)
-    return dyad(p["down"], jax.nn.silu(g) * u, "ot", prec)
-
-
-def hidden(m, params, tokens, prec: str, q_block: int = 1024):
-    """Final-norm hidden states ``(S, d)`` of one sequence."""
-    S = tokens.shape[0]
-    pos = jnp.arange(S)
-    x = params["embed"]["table"][tokens]
-    if m["opt"]:
-        x = x + params["pos"]["table"][:S]
-    norm = layernorm if m["opt"] else rmsnorm
-
-    def layer(x, lp):
-        x = x + attention(m, lp["attn"], norm(lp["norm1"], x, m["eps"]),
-                          pos, prec, q_block)
-        x = x + ff(m, lp["mlp"], norm(lp["norm2"], x, m["eps"]), prec)
-        return x, None
-
-    x, _ = jax.lax.scan(layer, x, params["layers"])
-    return norm(params["final_norm"], x, m["eps"])
-
-
-def logits_at(m, params, tokens, where, prec: str):
-    """Logits ``(len(where), vocab)`` of one sequence at positions
-    ``where`` (tied unembedding)."""
-    h = hidden(m, params, tokens, prec)[where]
-    return mm("sd,vd->sv", h, params["embed"]["table"], prec)
-
-
-def nll_sum(m, params, tokens, labels, prec: str):
-    """Summed next-token negative log-likelihood of a block of sequences."""
-    def one(t, y):
-        h = hidden(m, params, t, prec)
-        z = mm("sd,vd->sv", h, params["embed"]["table"], prec)
-        gold = jnp.take_along_axis(z, y[:, None], -1)[:, 0]
-        return jnp.sum(jax.nn.logsumexp(z, -1) - gold)
-    return sum(one(tokens[i], labels[i]) for i in range(tokens.shape[0]))
+    return jnp.concatenate(outs, 0).reshape(S, H * v.shape[-1])
 
 
 def decays(path) -> bool:

@@ -4,8 +4,9 @@
     python3 chipbench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 Run from the root of a checkout.  The cell ``<name>`` of ``BENCHMARK.json``
-names its configuration file; its workload file is
-``chipbench/workloads/<name>.json``, whose ``runner`` (``train`` or
+names its configuration file, which names its model module under
+``chipbench/models/`` (weights, reference and work counts); its workload
+file is ``chipbench/workloads/<name>.json``, whose ``runner`` (``train`` or
 ``serve``) runs it and whose ``traffic.kind`` names the generator under
 ``chipbench/traffic/``.  Each metric is read by ``chipbench/metrics/<metric
 name>.py``.  The run makes its weights and inputs from ``--seed``, warms up

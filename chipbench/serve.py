@@ -1,4 +1,4 @@
-"""The serving runner: Qwen3-style models through the program's
+"""The serving runner: decoder models through the program's
 ``ContinuousBatchingEngine`` on its paged KV cache, fed by a traffic
 module (``traffic/<kind>.py``) from one thread.
 
@@ -18,7 +18,7 @@ import time
 
 import numpy as np
 
-from chipbench import common, reference, weights, work
+from chipbench import common, models
 
 DRAIN_S = 60.0
 
@@ -117,9 +117,10 @@ def run(cell: dict, args, devices, t_process: float):
     from repro.serve import ContinuousBatchingEngine
 
     conf, wl = cell["config"], cell["workload"]
-    m = weights.dims(conf)
+    model = models.of(conf)
+    m = model.dims(conf)
     cfg = common.program_cfg(conf)
-    params = weights.make_jit(conf, args.seed)
+    params = models.make_jit(conf, args.seed)
     eng = ContinuousBatchingEngine(
         cfg, params, n_slots=wl["slots"], max_len=wl["max_len"],
         page_size=wl["page_size"], prefill_chunk=wl["prefill_chunk"],
@@ -199,7 +200,8 @@ def run(cell: dict, args, devices, t_process: float):
     del eng, params, feeder.eng
     gc.collect()
 
-    rec = {"w0": w0, "w1": w1, "t_end": t_end, "setup_s": setup_s, "m": m,
+    rec = {"w0": w0, "w1": w1, "t_end": t_end, "setup_s": setup_s,
+           "model": model, "m": m,
            "reqs": feeder.reqs, "steps": feeder.steps[n0:n1],
            "decode_ms": decode_ms, "trace": feeder.trace,
            "open_loop": traffic.open_loop, "queue": (q0, q1)}
@@ -247,13 +249,13 @@ def token_gaps(conf: dict, m: dict, seed: int, recs: list, seq_len: int,
     import jax
     import jax.numpy as jnp
 
-    params = weights.make_jit(conf, seed)
+    model = models.of(conf)
+    params = models.make_jit(conf, seed)
 
     def gaps(p, seq, where, served):
-        z = reference.logits_at(m, p, seq, where, "fp32")
+        z = model.logits_at(m, p, seq, where, "fp32")
         if control:
-            served = jnp.argmax(reference.logits_at(m, p, seq, where, "fp8"),
-                                -1)
+            served = jnp.argmax(model.logits_at(m, p, seq, where, "fp8"), -1)
         pick = jnp.take_along_axis(z, served[:, None], -1)[:, 0]
         return jnp.max(z, -1) - pick
 
@@ -295,7 +297,7 @@ def flops_in_window(rec: dict) -> float:
     """Model FLOPs of the work the window completed: every decode token
     emitted in it at its context, and the whole prefill of every request
     whose first token came in it."""
-    m, w0, w1 = rec["m"], rec["w0"], rec["w1"]
+    model, m, w0, w1 = rec["model"], rec["m"], rec["w0"], rec["w1"]
     total = 0.0
     for r in rec["reqs"].values():
         P = len(r["prompt"])
@@ -303,9 +305,9 @@ def flops_in_window(rec: dict) -> float:
             if not w0 <= t <= w1:
                 continue
             if k == 0:
-                total += work.serve_step_flops(
+                total += model.serve_step_flops(
                     m, {"chunks": [(0, P, True)], "contexts": []})
             else:
-                total += work.serve_step_flops(
+                total += model.serve_step_flops(
                     m, {"chunks": [], "contexts": [P + k]})
     return total

@@ -6,27 +6,18 @@ import argparse
 import copy
 import os
 
-from chipbench import common
-
-OPT = {"hidden_size": 64, "num_hidden_layers": 2, "ffn_dim": 128,
-       "num_attention_heads": 4, "vocab_size": 256,
-       "max_position_embeddings": 128, "word_embed_proj_dim": 64}
-OPT_PROGRAM = {"n_layers": 2, "d_model": 64, "vocab_size": 256, "n_heads": 4,
-               "n_kv_heads": 4, "head_dim": 16, "d_ff": 128,
-               "max_position": 128}
-QWEN = {"hidden_size": 64, "num_hidden_layers": 2, "intermediate_size": 128,
-        "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
-        "vocab_size": 256}
-QWEN_PROGRAM = {"n_layers": 2, "d_model": 64, "vocab_size": 256,
-                "n_heads": 4, "n_kv_heads": 2, "head_dim": 16, "d_ff": 128,
-                "attn_chunk": None}
+from chipbench import common, models
 
 
-def config(name: str, compute: str = None) -> dict:
+def config(name: str, compute: str = None, linear: str = None) -> dict:
+    """The configuration file ``name`` cut to the smoke sizes its model
+    module gives, with the program's ``compute`` dtype or ``linear`` where
+    given."""
     conf = common.load_json(os.path.join(common.BENCH, "configs",
                                          name + ".json"))
-    small, prog = (OPT, OPT_PROGRAM) if name.startswith("opt") else (
-        QWEN, QWEN_PROGRAM)
+    if linear:
+        conf["program"]["linear"] = linear
+    small, prog = models.of(conf).smoke(conf)
     conf.update(small)
     conf["program"]["overrides"] = {**conf["program"]["overrides"], **prog}
     if compute:
@@ -60,11 +51,11 @@ def listed(name: str) -> dict:
             "per_layer": []}
 
 
-def cell(name: str, compute: str = None) -> dict:
+def cell(name: str, compute: str = None, linear: str = None) -> dict:
     """The benchmark's cell ``name`` with its configuration and workload cut
     to smoke size."""
     c = copy.deepcopy(listed(name))
-    c["config"] = config(c["entry"]["config"], compute)
+    c["config"] = config(c["entry"]["config"], compute, linear)
     wl = c["workload"]
     if wl["runner"] == "train":
         wl["traffic"].update(batch=2, seq=32, ring=4)

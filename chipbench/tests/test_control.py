@@ -4,7 +4,7 @@ Qwen3-shaped model with a vocabulary wide enough that the top logits lie
 close, and OPT-shaped training at smoke size."""
 import numpy as np
 
-from chipbench import common, reference, serve, train, weights
+from chipbench import common, models, serve, train
 from chipbench.tests import cells
 
 WIDER = {"hidden_size": 256, "num_hidden_layers": 4, "intermediate_size": 768,
@@ -18,9 +18,10 @@ def greedy_requests(conf, seed, n_req=3, prompt=48, new=24):
     import jax
     import jax.numpy as jnp
 
-    m = weights.dims(conf)
-    params = weights.make_jit(conf, seed)
-    fwd = jax.jit(lambda p, t: reference.logits_at(
+    model = models.of(conf)
+    m = model.dims(conf)
+    params = models.make_jit(conf, seed)
+    fwd = jax.jit(lambda p, t: model.logits_at(
         m, p, t, jnp.arange(t.shape[0]), "fp32")[-1])
     rng = common.rng(seed, 3)
     reqs = {}

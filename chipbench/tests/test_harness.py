@@ -43,3 +43,22 @@ def test_cell_runs_end_to_end(name, capsys):
     assert set(line["metrics"]) == want
     for v in line["metrics"].values():
         assert v["value"] > 0
+
+
+def test_dense_ff_cell_is_correct(capsys):
+    """``opt-125m.train`` with the configuration's ``program.linear`` set to
+    ``dense``: no harness file knows it, yet the run is correct (no DYAD
+    kernel runs, so on the CPU no kernel runs in the interpreter)."""
+    from repro import obs
+
+    obs.reset_route_counts()    # a run's process starts with none
+    name = "opt-125m.train"
+    cell, args = cells.cell(name, linear="dense"), cells.args(name, seed=7)
+    devices = jax.devices()
+    rec, out = run.execute(cell, args, devices)
+    assert out["ok"] and out["problems"] == [], out
+    assert rec["model"].train_dyad_mm_calls(rec["m"], 2, 32) == []
+    run.finish(cell, args, devices, PEAKS, rec, out)
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["correct"] is True
+    assert line["metrics"]["train_tokens_per_s"]["value"] > 0

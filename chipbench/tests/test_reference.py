@@ -5,47 +5,58 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import common, reference, weights
+from chipbench import common, models, reference
 from chipbench.tests import cells
 
 
-@pytest.mark.parametrize("name", ["opt-125m", "qwen3-0.6b"])
-def test_weights_have_the_program_layout(name):
+# each configuration as published, and OPT with dense ff layers (its
+# ``program.linear`` set to ``dense``)
+CONFIGS = pytest.mark.parametrize(
+    "name,linear", [("opt-125m", None), ("qwen3-0.6b", None),
+                    ("opt-125m", "dense")],
+    ids=["opt-125m", "qwen3-0.6b", "opt-125m-dense"])
+
+
+@CONFIGS
+def test_weights_have_the_program_layout(name, linear):
     from repro.models import model
 
-    conf = cells.config(name, compute="float32")
+    conf = cells.config(name, compute="float32", linear=linear)
     cfg = common.program_cfg(conf)
     want = jax.eval_shape(lambda: model.init_params(cfg, jax.random.PRNGKey(0)))
-    got = jax.eval_shape(lambda: weights.make(conf, np.zeros(2, np.uint32)))
+    got = jax.eval_shape(lambda: models.of(conf).make(
+        conf, np.zeros(2, np.uint32)))
     assert jax.tree.structure(got) == jax.tree.structure(want)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert (a.shape, a.dtype) == (b.shape, b.dtype)
 
 
-@pytest.mark.parametrize("name", ["opt-125m", "qwen3-0.6b"])
-def test_reference_matches_program_forward(name):
+@CONFIGS
+def test_reference_matches_program_forward(name, linear):
     from repro.models import model
 
-    conf = cells.config(name, compute="float32")
+    conf = cells.config(name, compute="float32", linear=linear)
     cfg = common.program_cfg(conf)
-    m = weights.dims(conf)
-    params = weights.make_jit(conf, 11)
+    ref = models.of(conf)
+    m = ref.dims(conf)
+    params = models.make_jit(conf, 11)
     tokens = np.random.default_rng(0).integers(0, m["vocab"], 24)
     with jax.default_matmul_precision("highest"):
         want = model.forward(cfg, params, {"tokens": jnp.asarray(tokens)[None]})[0][0]
-    got = reference.logits_at(m, params, jnp.asarray(tokens),
-                              jnp.arange(24), "fp32")
+    got = ref.logits_at(m, params, jnp.asarray(tokens), jnp.arange(24),
+                        "fp32")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
 
 
 def test_control_departs_from_reference():
     conf = cells.config("qwen3-0.6b")
-    m = weights.dims(conf)
-    params = weights.make_jit(conf, 5)
+    model = models.of(conf)
+    m = model.dims(conf)
+    params = models.make_jit(conf, 5)
     tokens = jnp.asarray(np.random.default_rng(1).integers(0, m["vocab"], 16))
-    a = reference.logits_at(m, params, tokens, jnp.arange(16), "fp32")
-    b = reference.logits_at(m, params, tokens, jnp.arange(16), "fp8")
+    a = model.logits_at(m, params, tokens, jnp.arange(16), "fp32")
+    b = model.logits_at(m, params, tokens, jnp.arange(16), "fp8")
     assert float(jnp.max(jnp.abs(a - b))) > 1e-3
 
 

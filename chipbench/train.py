@@ -18,7 +18,7 @@ import time
 
 import numpy as np
 
-from chipbench import common, reference, weights
+from chipbench import common, models, reference
 
 
 def _optimizer(hp: dict):
@@ -33,6 +33,11 @@ def _norms_host(tree_norms) -> dict:
     return {k: float(v) for k, v in tree_norms.items()}
 
 
+def _first_moment(params, opt):
+    """AdamW's first moment in the program's train state."""
+    return opt["m"]
+
+
 class Program:
     """The compiled train step of a cell and what reads its state."""
 
@@ -43,23 +48,24 @@ class Program:
 
         self.conf, self.wl = conf, wl = cell["config"], cell["workload"]
         self.spec, hp = wl["traffic"], wl["optimizer"]
-        self.m = weights.dims(conf)
+        self.model = model = models.of(conf)
+        self.m = model.dims(conf)
         self.batches = common.load_module(
             f"{common.BENCH}/traffic/{self.spec['kind']}.py")
         opt = _optimizer(hp)
         cfg = common.program_cfg(conf)
 
         def init(kd):
-            params = weights.make(conf, kd)
-            return {"params": params, "opt": opt.init(params)}
+            params = model.make(conf, kd)
+            return dict(params=params, opt=opt.init(params))
 
         self.init = jax.jit(init)
         self.step = jax.jit(make_train_step(cfg, opt), donate_argnums=0)
         self.m_norms = jax.jit(lambda st: reference.leaf_norms(jax.tree.map(
-            lambda x: x / (1.0 - hp["b1"]), st["opt"]["m"])))
+            lambda x: x / (1.0 - hp["b1"]), _first_moment(**st))))
         self.dp_norms = jax.jit(lambda st, kd: reference.leaf_norms(
             jax.tree.map(lambda a, b: a - b, st["params"],
-                         weights.make(conf, kd))))
+                         model.make(conf, kd))))
 
     def first_steps(self, seed: int):
         """State, ring and the program's readings after three steps."""
@@ -87,7 +93,7 @@ def run(cell: dict, args, devices, t_process: float):
     from chipbench import tracing
 
     prog = Program(cell)
-    wl, spec = prog.wl, prog.spec
+    wl, spec, model, m = prog.wl, prog.spec, prog.model, prog.m
     step = prog.step
     state, ring, readings = prog.first_steps(args.seed)
     common.log(f"losses of steps 1-3: {readings['loss']}")
@@ -138,8 +144,7 @@ def run(cell: dict, args, devices, t_process: float):
     del state, mt, ring, step, pending, prog
     gc.collect()
 
-    rec = {"w0": w0, "w1": w1, "setup_s": setup_s, "m": weights.dims(
-               cell["config"]),
+    rec = {"w0": w0, "w1": w1, "setup_s": setup_s, "model": model, "m": m,
            "steps": n, "tokens": n * tokens_per_step, "seq": spec["seq"],
            "batch": spec["batch"], "trace": trace}
     problems = []
@@ -167,14 +172,15 @@ def reference_readings(conf: dict, wl: dict, seed: int, prec: str = "fp32",
     import jax.numpy as jnp
 
     spec, hp = wl["traffic"], wl["optimizer"]
-    m = weights.dims(conf)
+    model = models.of(conf)
+    m = model.dims(conf)
     batches_mod = common.load_module(
         f"{common.BENCH}/traffic/{spec['kind']}.py")
-    p0 = weights.make_jit(conf, seed)
+    p0 = models.make_jit(conf, seed)
     batches = batches_mod.ring(spec, m["vocab"], seed, count=3)
     blk = wl["check"]["rows"]
     vg = jax.jit(jax.value_and_grad(
-        lambda p, t, y: reference.nll_sum(m, p, t, y, prec)))
+        lambda p, t, y: model.nll_sum(m, p, t, y, prec)))
     update = jax.jit(lambda p, s, g, k: reference.adamw_step(p, s, g, k, hp))
     params = p0
     opt_state = {"m": jax.tree.map(jnp.zeros_like, p0),
