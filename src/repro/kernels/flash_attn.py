@@ -35,6 +35,10 @@ as the DYAD matmul/ff kernels (:mod:`repro.kernels.dyad_mm`):
   hit the same kernel.  Key tiles wholly beyond ``idx`` (unwrapped cache)
   are skipped with ``pl.when``.
 
+The value width may differ from the query/key width (latent attention):
+the v, output and dO tiles and the accumulators that read them take v's
+width; with equal widths each kernel is the same call as before.
+
 Backward (:func:`flash_prefill_grads`): the standard two-kernel flash
 backward — probabilities are RECOMPUTED per tile from the saved
 log-sum-exp (``lse = m + log l``), never stored.  ``dq`` runs on the
@@ -317,6 +321,7 @@ def _prefill_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, *rest,
 def _prefill_impl(q, k, v, qoff, koff, *, bQ, bT, G, causal, window, t_real,
                   save_lse, interpret):
     B, K, SG, h = q.shape
+    dv = v.shape[3]
     Tp = k.shape[2]
     nq, nt = SG // (bQ * G), Tp // bT
     grid = (B, K, nq, nt)
@@ -326,9 +331,11 @@ def _prefill_impl(q, k, v, qoff, koff, *, bQ, bT, G, causal, window, t_real,
                           lambda b, kh, qi, ki, qo, ko: (b, kh, qi, 0))
     kv_spec = pl.BlockSpec((1, 1, bT, h),
                            _kv_index_map(causal, window, bQ, bT, nt))
-    o_spec = pl.BlockSpec((1, 1, bQG, h),
+    v_spec = kv_spec if dv == h else pl.BlockSpec(
+        (1, 1, bT, dv), _kv_index_map(causal, window, bQ, bT, nt))
+    o_spec = pl.BlockSpec((1, 1, bQG, dv),
                           lambda b, kh, qi, ki, qo, ko: (b, kh, qi, 0))
-    out_shape = jax.ShapeDtypeStruct((B, K, SG, h), q.dtype)
+    out_shape = jax.ShapeDtypeStruct((B, K, SG, dv), q.dtype)
     out_specs, out_shapes = [o_spec], [out_shape]
     if save_lse:
         out_specs.append(pl.BlockSpec(
@@ -344,12 +351,12 @@ def _prefill_impl(q, k, v, qoff, koff, *, bQ, bT, G, causal, window, t_real,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
-            in_specs=[q_spec, kv_spec, kv_spec],
+            in_specs=[q_spec, kv_spec, v_spec],
             out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((bQG, _STATE_LANES), jnp.float32),
                 pltpu.VMEM((bQG, _STATE_LANES), jnp.float32),
-                pltpu.VMEM((bQG, h), jnp.float32),
+                pltpu.VMEM((bQG, dv), jnp.float32),
             ],
         ),
         out_shape=out_shapes,
@@ -389,11 +396,14 @@ def flash_prefill(
 ):
     """Fused flash attention forward.
 
-    q: (B, S, K, G, h); k, v: (B, T, K, h) — the layer-natural GQA layout.
-    Query position ``s`` sits at ``q_off + s``; key ``t`` at ``k_off + t``
-    (scalars or (B,) vectors — positions must be CONTIGUOUS from the
-    offset, which every dispatch site guarantees).  Returns
-    ``(out (B,S,K,G,h), lse)`` where ``lse`` is the (B, K, S*G) fp32
+    q: (B, S, K, G, h); k: (B, T, K, h); v: (B, T, K, dv) — the
+    layer-natural GQA layout; the value width ``dv`` may differ from the
+    query/key width ``h`` (latent attention: 192 against 128), and the
+    scores are scaled by ``1/sqrt(h)``.  Query position ``s`` sits at
+    ``q_off + s``; key ``t`` at ``k_off + t`` (scalars or (B,) vectors —
+    positions must be CONTIGUOUS from the offset, which every dispatch
+    site guarantees).  Returns
+    ``(out (B,S,K,G,dv), lse)`` where ``lse`` is the (B, K, S*G) fp32
     log-sum-exp when ``save_lse`` (the backward residual), else None.
     """
     B, S, K, G, h = q.shape
@@ -466,6 +476,7 @@ def _dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _dq_impl(q, k, v, do, lse, delta, qoff, koff, *, bQ, bT, G, causal,
              window, t_real, interpret):
     B, K, SG, h = q.shape
+    dv = v.shape[3]
     Tp = k.shape[2]
     nq, nt = SG // (bQ * G), Tp // bT
     bQG = bQ * G
@@ -474,6 +485,11 @@ def _dq_impl(q, k, v, do, lse, delta, qoff, koff, *, bQ, bT, G, causal,
                           lambda b, kh, qi, ki, qo, ko: (b, kh, qi, 0))
     kv_spec = pl.BlockSpec((1, 1, bT, h),
                            _kv_index_map(causal, window, bQ, bT, nt))
+    v_spec, do_spec = (kv_spec, q_spec) if dv == h else (
+        pl.BlockSpec((1, 1, bT, dv),
+                     _kv_index_map(causal, window, bQ, bT, nt)),
+        pl.BlockSpec((1, 1, bQG, dv),
+                     lambda b, kh, qi, ki, qo, ko: (b, kh, qi, 0)))
     row_spec = pl.BlockSpec((1, 1, bQG, 1),
                             lambda b, kh, qi, ki, qo, ko: (b, kh, qi, 0))
     scale = 1.0 / float(h) ** 0.5
@@ -484,7 +500,7 @@ def _dq_impl(q, k, v, do, lse, delta, qoff, koff, *, bQ, bT, G, causal,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, K, nq, nt),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+            in_specs=[q_spec, kv_spec, v_spec, do_spec, row_spec, row_spec],
             out_specs=[q_spec],
             scratch_shapes=[pltpu.VMEM((bQG, h), jnp.float32)],
         ),
@@ -576,6 +592,7 @@ def _dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _dkv_impl(q, k, v, do, lse, delta, qoff, koff, *, bQ, bT, G, causal,
               window, t_real, interpret):
     B, K, SG, h = q.shape
+    dv = v.shape[3]
     Tp = k.shape[2]
     nq, nt = SG // (bQ * G), Tp // bT
     bQG = bQ * G
@@ -584,23 +601,28 @@ def _dkv_impl(q, k, v, do, lse, delta, qoff, koff, *, bQ, bT, G, causal,
                           _q_index_map(causal, window, bQ, bT, nq))
     kv_spec = pl.BlockSpec((1, 1, bT, h),
                            lambda b, kh, ki, qi, qo, ko: (b, kh, ki, 0))
+    v_spec, do_spec = (kv_spec, q_spec) if dv == h else (
+        pl.BlockSpec((1, 1, bT, dv),
+                     lambda b, kh, ki, qi, qo, ko: (b, kh, ki, 0)),
+        pl.BlockSpec((1, 1, bQG, dv),
+                     _q_index_map(causal, window, bQ, bT, nq)))
     row_spec = pl.BlockSpec((1, 1, bQG, 1),
                             _q_index_map(causal, window, bQ, bT, nq))
     scale = 1.0 / float(h) ** 0.5
     body = functools.partial(_dkv_kernel, G=G, bQ=bQ, bT=bT, t_real=t_real,
                              causal=causal, window=window, scale=scale)
-    out_sds = jax.ShapeDtypeStruct((B, K, Tp, h), k.dtype)
     return pl.pallas_call(
         body,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, K, nt, nq),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-            out_specs=[kv_spec, kv_spec],
+            in_specs=[q_spec, kv_spec, v_spec, do_spec, row_spec, row_spec],
+            out_specs=[kv_spec, v_spec],
             scratch_shapes=[pltpu.VMEM((bT, h), jnp.float32),
-                            pltpu.VMEM((bT, h), jnp.float32)],
+                            pltpu.VMEM((bT, dv), jnp.float32)],
         ),
-        out_shape=[out_sds, out_sds],
+        out_shape=[jax.ShapeDtypeStruct((B, K, Tp, h), k.dtype),
+                   jax.ShapeDtypeStruct((B, K, Tp, dv), v.dtype)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
@@ -625,7 +647,9 @@ def flash_prefill_grads(
     block_k: int = None,
     interpret: bool = False,
 ):
-    """Flash backward: (dq, dk, dv) at the layer-natural layouts.
+    """Flash backward: (dq, dk, dv) at the layer-natural layouts (``v``,
+    ``o`` and ``do`` may be narrower than ``q`` and ``k``, as in
+    :func:`flash_prefill`).
 
     ``lse`` is the (B, K, S*G) residual from ``flash_prefill(...,
     save_lse=True)``; probabilities are recomputed per tile from it —

@@ -58,8 +58,9 @@ import jax
 import jax.numpy as jnp
 
 from repro import faults, obs
-from repro.kernels import flash_attn, ref
-from repro.kernels.dyad_mm import (dyad_ff_fused, dyad_ff_fused_q,
+from repro.kernels import dyad_gmm, flash_attn, ref
+from repro.kernels.dyad_mm import (_block_major, dyad_ff_fused,
+                                   dyad_ff_fused_q,
                                    dyad_mm_blocks, dyad_mm_blocks_q,
                                    dyad_mm_blocks_two, dyad_mm_blocks_two_q,
                                    dyad_mm_dgrad, dyad_mm_dgrad_two,
@@ -565,6 +566,58 @@ def dyad_ff_quant(params, x, *, act: str = "gelu"):
                                       interpret=interpret)
     y = ref.combine(z1, z2, "ot")
     return y.reshape(*lead, n * d_out)
+
+
+# -- the grouped expert op ----------------------------------------------------
+#
+# ``dyad_gmm`` is a DYAD-IT projection of rows sorted by expert, each row
+# through its own expert's weights (:mod:`repro.kernels.dyad_gmm`), as one
+# differentiable op.  The backward always runs the grouped Pallas kernels
+# (in the interpreter off-TPU): there is no XLA lowering of it to choose.
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _dyad_gmm(x, w1, w2, starts, ends, row_tile):
+    out, _ = _dyad_gmm_fwd(x, w1, w2, starts, ends, row_tile)
+    return out
+
+
+def _dyad_gmm_fwd(x, w1, w2, starts, ends, row_tile):
+    n = w1.shape[1]
+    x1, x2 = (_block_major(v) for v in ref.block_views(x, n, "it"))
+    z = dyad_gmm.gmm(x1, x2, w1.astype(x.dtype), w2.astype(x.dtype),
+                     starts, ends, row_tile=row_tile,
+                     interpret=_interpret())
+    out = _block_major(z).reshape(x.shape[0], -1)
+    return out, (x1, x2, w1, w2, starts, ends)
+
+
+def _dyad_gmm_bwd(row_tile, resids, g):
+    x1, x2, w1, w2, starts, ends = resids
+    n, M, _ = x1.shape
+    interpret = _interpret()
+    z = _block_major(g.astype(x1.dtype).reshape(M, n, -1))
+    dx1, dx2 = dyad_gmm.gmm_dgrad(z, w1.astype(x1.dtype),
+                                  w2.astype(x1.dtype), starts, ends,
+                                  row_tile=row_tile, interpret=interpret)
+    dx = ref.unview(_block_major(dx1), _block_major(dx2), "it")
+    dw1, dw2 = dyad_gmm.gmm_wgrad(x1, x2, z, starts, ends,
+                                  row_tile=row_tile, interpret=interpret)
+    return (dx, dw1.astype(w1.dtype), dw2.astype(w2.dtype),
+            _int_zero(starts), _int_zero(ends))
+
+
+_dyad_gmm.defvjp(_dyad_gmm_fwd, _dyad_gmm_bwd)
+
+
+def dyad_gmm_it(x, w1, w2, starts, ends, *,
+                row_tile: int = dyad_gmm.ROW_TILE):
+    """DYAD-IT over rows sorted by expert: ``x (M, f_in)`` -> ``(M, f_out)``,
+    row ``r`` of group ``e`` (``starts[e] <= r < ends[e]``, each
+    ``starts[e]`` a multiple of ``row_tile``) through expert ``e``'s
+    ``w1[e]``, ``w2[e]`` (``(E, n, d_out, d_in)``); rows outside every
+    group come out undefined and take no part in the weights' gradient."""
+    return _dyad_gmm(x, w1, w2, starts, ends, row_tile)
 
 
 # -- the flash-attention ops --------------------------------------------------

@@ -1,5 +1,9 @@
 """Mixture-of-Experts ff module (top-k routed + shared experts).
 
+Two layers: the GShard capacity layer below, which holds every expert and
+drops overflow tokens, and (at the end of this file) the dropless layer of
+one chip's share of the experts, selected by ``ModelCfg.n_experts_held``.
+
 Dispatch is GShard-style with **batch rows as capacity groups** and an optional
 ``lax.scan`` over sequence chunks, chosen so the layer composes with GSPMD
 without shard_map:
@@ -29,6 +33,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import factory, linear
+from repro.kernels import dyad_gmm
+from repro.kernels import ops as kops
 from repro.layers import mlp as mlp_lib
 from repro.sharding import ctx as shard_ctx
 
@@ -192,3 +198,208 @@ def apply_moe(
         y = y + g.astype(x.dtype) * mlp_lib.apply_mlp(
             params["shared"], x, lin_cfg, act=act)
     return y, aux
+
+
+# -- one chip's share of a dropless expert layer ------------------------------
+#
+# The layer of an expert-parallel deployment as one chip runs it: the router
+# scores every token against ALL ``n_experts`` and picks its ``top_k``, the
+# chip computes the part of the result that its own experts (ids
+# ``first .. first + n_held``) give, for every token routed to them, and
+# the shared experts (which every chip computes alike) are added.  Nothing is
+# dropped: the sorted-row buffer is sized for the worst case, every token
+# copy routed to a held expert gets a row, and the grouped kernel computes
+# only the rows routed here (``kernels/dyad_gmm.py``).  On one chip there is
+# no exchange; a deployment would send the other experts' token copies to
+# their chips.
+
+
+def init_moe_share(key, d_model: int, expert_d_ff: int, n_experts: int,
+                   n_held: int, lin_cfg: factory.LinearCfg, *,
+                   n_shared: int = 0, dtype=jnp.float32):
+    """Router over all ``n_experts`` (with the selection bias
+    ``score_bias``), ``n_held`` SwiGLU experts and the shared experts as one
+    SwiGLU of ``n_shared * expert_d_ff``."""
+    ks = jax.random.split(key, 3)
+    p = {
+        "router": linear.init(ks[0], d_model, n_experts, bias=False,
+                              dtype=dtype),
+        "score_bias": jnp.zeros((n_experts,), dtype),
+        "experts": jax.vmap(lambda k: mlp_lib.init_mlp(
+            k, d_model, expert_d_ff, lin_cfg, act="swiglu", dtype=dtype))(
+                jax.random.split(ks[1], n_held)),
+    }
+    if n_shared:
+        p["shared"] = mlp_lib.init_mlp(ks[2], d_model, n_shared * expert_d_ff,
+                                       lin_cfg, act="swiglu", dtype=dtype)
+    return p
+
+
+def route_scores(params, x, *, top_k: int, score: str, norm_topk: bool,
+                 routed_scale: float):
+    """``x (T, D)`` -> (weights ``(T, top_k)`` float32, expert ids).  The
+    router runs in float32 at full precision.  Sigmoid scores pick their
+    top_k by score + ``score_bias`` and weigh by the score alone."""
+    logits = jnp.einsum("td,ed->te", x.astype(jnp.float32),
+                        params["router"]["w"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    if score == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        choice = s + jax.lax.stop_gradient(
+            params["score_bias"].astype(jnp.float32))
+    else:
+        s = choice = jax.nn.softmax(logits, axis=-1)
+    _, idx = jax.lax.top_k(choice, top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if norm_topk:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return w * routed_scale, idx
+
+
+def share_layout(idx, *, first: int, n_held: int, row_tile: int):
+    """Where each token copy routed to a held expert goes in the sorted-row
+    buffer.  Returns ``dest`` ``(T, top_k)`` (the buffer size ``M`` for a
+    copy routed elsewhere), ``row_copy`` ``(M,)`` (the flat copy index
+    ``t * top_k + j`` of each row, ``T * top_k`` for a row that holds
+    none), the group ``starts`` ``(n_held + 1,)``, ``ends`` and ``sizes``
+    ``(n_held,)``."""
+    T, k = idx.shape
+    local = (idx - first).reshape(-1)
+    held = (local >= 0) & (local < n_held)
+    group = jnp.where(held, local, 0)
+    onehot = ((group[:, None] == jnp.arange(n_held)) & held[:, None]
+              ).astype(jnp.int32)
+    sizes = onehot.sum(0)
+    rank = jnp.take_along_axis(jnp.cumsum(onehot, 0), group[:, None],
+                               axis=1)[:, 0] - 1
+    starts = dyad_gmm.group_starts(sizes, row_tile)
+    M = dyad_gmm.buffer_rows(T * min(k, n_held), n_held, row_tile)
+    dest = jnp.where(held, starts[group] + rank, M)
+    row_copy = jnp.full((M,), T * k, jnp.int32).at[dest].set(
+        jnp.arange(T * k, dtype=jnp.int32), mode="drop")
+    return (dest.reshape(T, k), row_copy, starts, starts[:n_held] + sizes,
+            sizes)
+
+
+def _rows_at(rows, dest):
+    """``rows[dest]`` for one column of ``dest`` ``(T,)``, zero where it is
+    past the buffer (a copy routed to another chip's expert).  The
+    buffer's rows outside every group are never read."""
+    M = rows.shape[0]
+    got = rows[jnp.minimum(dest, M - 1)]
+    return jnp.where((dest < M)[:, None], got, 0)
+
+
+# Dispatch and combine move rows by gathers in both directions (their
+# autodiff would scatter-add); each sums over the top_k copies one column
+# at a time, so that no (T, top_k, D) array exists.
+
+
+@jax.custom_vjp
+def _dispatch(x, row_copy, dest):
+    """Token rows ``x (T, D)`` -> sorted rows ``(M, D)`` (token 0's row
+    where a row holds no copy)."""
+    k = dest.shape[1]
+    return x[jnp.where(row_copy < x.shape[0] * k, row_copy // k, 0)]
+
+
+def _dispatch_fwd(x, row_copy, dest):
+    return _dispatch(x, row_copy, dest), (row_copy, dest)
+
+
+def _dispatch_bwd(res, g):
+    row_copy, dest = res
+    dx = sum(_rows_at(g, dest[:, j]).astype(jnp.float32)
+             for j in range(dest.shape[1]))
+    return dx.astype(g.dtype), kops._int_zero(row_copy), kops._int_zero(dest)
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(rows, w, row_copy, dest):
+    """``y[t] = Σ_j w[t, j] · rows[dest[t, j]]`` over the copies held here,
+    summed in float32, in the rows' dtype."""
+    y = sum(_rows_at(rows, dest[:, j]).astype(jnp.float32) * w[:, j, None]
+            for j in range(dest.shape[1]))
+    return y.astype(rows.dtype)
+
+
+def _combine_fwd(rows, w, row_copy, dest):
+    return _combine(rows, w, row_copy, dest), (rows, w, row_copy, dest)
+
+
+def _combine_bwd(res, g):
+    rows, w, row_copy, dest = res
+    T, k = w.shape
+    has = row_copy < T * k
+    c = jnp.where(has, row_copy, 0)
+    scale = w.reshape(-1)[c].astype(g.dtype)
+    drows = jnp.where(has[:, None], g[c // k] * scale[:, None], 0)
+    g32 = g.astype(jnp.float32)
+    dw = jnp.stack([jnp.sum(_rows_at(rows, dest[:, j]).astype(jnp.float32)
+                            * g32, axis=-1) for j in range(k)], axis=1)
+    return (drows.astype(rows.dtype), dw, kops._int_zero(row_copy),
+            kops._int_zero(dest))
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def apply_moe_share(params, x, lin_cfg: factory.LinearCfg, *, top_k: int,
+                    first: int, score: str = "sigmoid",
+                    norm_topk: bool = True, routed_scale: float = 1.0,
+                    chunk: Optional[int] = None,
+                    row_tile: int = dyad_gmm.ROW_TILE):
+    """``x (B, S, D)`` -> (this chip's part of the layer's output, counters
+    ``routed_rows`` (token copies computed here) and ``max_expert_rows``
+    (those of the busiest held expert)).  The held experts are DYAD-IT
+    projections run by the grouped kernel.  ``chunk`` tokens at a time
+    (a divisor of B * S) go through the sorted-row buffer, each chunk
+    recomputed in the backward, so that the buffer, sized for the worst
+    case of a dropless layer, is a chunk's."""
+    B, S, D = x.shape
+    T = B * S
+    xf = x.reshape(T, D)
+    ex = params["experts"]
+    n_held = ex["up"]["w1"].shape[0]
+    with jax.named_scope("router"):
+        w, idx = route_scores(params, xf, top_k=top_k, score=score,
+                              norm_topk=norm_topk, routed_scale=routed_scale)
+
+    def routed(xc, wc, ic):
+        with jax.named_scope("router"):
+            dest, row_copy, starts, ends, sizes = share_layout(
+                ic, first=first, n_held=n_held, row_tile=row_tile)
+        with jax.named_scope("experts"):
+            rows = _dispatch(xc, row_copy, dest)
+
+            def proj(name, h):
+                return kops.dyad_gmm_it(h, ex[name]["w1"], ex[name]["w2"],
+                                        starts, ends, row_tile=row_tile)
+
+            h = jax.nn.silu(proj("gate", rows)) * proj("up", rows)
+            rows = proj("down", h)
+        with jax.named_scope("router"):
+            return _combine(rows, wc, row_copy, dest), sizes
+
+    C = min(chunk or T, T)
+    if T % C:
+        raise ValueError(f"moe chunk {C} does not divide {T} tokens")
+    if C == T:
+        y, sizes = routed(xf, w, idx)
+    else:
+        y, sizes = jax.lax.map(
+            jax.checkpoint(lambda a: routed(*a)),
+            (xf.reshape(T // C, C, D), w.reshape(T // C, C, top_k),
+             idx.reshape(T // C, C, top_k)))
+        y, sizes = y.reshape(B * S, D), sizes.sum(0)
+    y = y.reshape(B, S, D)
+    if "shared" in params:
+        with jax.named_scope("shared"):
+            y = y + mlp_lib.apply_mlp(params["shared"], x, lin_cfg,
+                                      act="swiglu")
+    stats = {"routed_rows": sizes.sum().astype(jnp.float32),
+             "max_expert_rows": sizes.max().astype(jnp.float32)}
+    return y, stats

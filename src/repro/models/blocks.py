@@ -1,8 +1,11 @@
 """Per-layer blocks for every model family.
 
-Each family uses ONE homogeneous block kind so the whole stack can be
+Each group of like layers uses ONE block kind so the group can be
 ``jax.lax.scan``-ed over stacked layer params (keeps compiled HLO size O(1) in
 depth — required to compile 126-layer models, and the idiomatic TPU pattern).
+A family has one group, except a moe stack with leading dense layers
+(``ModelCfg.n_dense_layers``: an ``lm`` group, then the ``moe`` group; see
+``models.model.layer_groups``).
 """
 from __future__ import annotations
 
@@ -12,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.layers import attention as attn_lib
+from repro.layers import mla as mla_lib
 from repro.layers import mlp as mlp_lib
 from repro.layers import moe as moe_lib
 from repro.layers import norms
@@ -26,11 +30,17 @@ def _init_norm(cfg: ModelCfg, dtype):
     return norms.init_rmsnorm(cfg.d_model, dtype)
 
 
+def norm(cfg: ModelCfg, p, x):
+    """The config's norm at its eps (the norm's default when unset)."""
+    kw = {} if cfg.norm_eps is None else {"eps": cfg.norm_eps}
+    if cfg.norm == "layernorm":
+        return norms.layernorm(p, x, **kw)
+    return norms.rmsnorm(p, x, **kw)
+
+
 def _apply_norm(cfg: ModelCfg, p, x):
     with jax.named_scope("norm"):
-        if cfg.norm == "layernorm":
-            return norms.layernorm(p, x)
-        return norms.rmsnorm(p, x)
+        return norm(cfg, p, x)
 
 
 def init_block(key, cfg: ModelCfg, kind: str):
@@ -38,7 +48,12 @@ def init_block(key, cfg: ModelCfg, kind: str):
     dtype = cfg.pdtype
     ks = jax.random.split(key, 6)
     p = {"norm1": _init_norm(cfg, dtype)}
-    if kind in ("lm", "moe", "hybrid", "enc", "dec_cross"):
+    if cfg.mla:
+        p["attn"] = mla_lib.init_mla(
+            ks[0], cfg.d_model, cfg.n_heads, cfg.kv_lora_rank,
+            cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
+            cfg.linear, dtype=dtype)
+    elif kind in ("lm", "moe", "hybrid", "enc", "dec_cross"):
         p["attn"] = attn_lib.init_attention(
             ks[0], cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd, cfg.linear,
             qkv_bias=cfg.qkv_bias, qk_norm=cfg.qk_norm, dtype=dtype)
@@ -58,7 +73,12 @@ def init_block(key, cfg: ModelCfg, kind: str):
             qkv_bias=cfg.qkv_bias, qk_norm=False, dtype=dtype)
     if kind != "ssm":
         p["norm2"] = _init_norm(cfg, dtype)
-        if kind == "moe":
+        if kind == "moe" and cfg.n_experts_held:
+            p["moe"] = moe_lib.init_moe_share(
+                ks[3], cfg.d_model, cfg.expert_d_ff, cfg.n_experts,
+                cfg.n_experts_held, cfg.linear, n_shared=cfg.n_shared,
+                dtype=dtype)
+        elif kind == "moe":
             p["moe"] = moe_lib.init_moe(
                 ks[3], cfg.d_model, cfg.expert_d_ff, cfg.n_experts, cfg.top_k,
                 cfg.linear, n_shared=cfg.n_shared, act=cfg.act,
@@ -71,6 +91,16 @@ def init_block(key, cfg: ModelCfg, kind: str):
 
 
 def _self_attn(p, cfg: ModelCfg, x, *, causal, cache, positions):
+    if cfg.mla:
+        if cache is not None or not causal:
+            raise NotImplementedError("latent attention has the causal "
+                                      "training path only")
+        return mla_lib.apply_mla(
+            p, x, n_heads=cfg.n_heads, kv_lora_rank=cfg.kv_lora_rank,
+            qk_nope=cfg.qk_nope_head_dim, qk_rope=cfg.qk_rope_head_dim,
+            v_dim=cfg.v_head_dim, lin_cfg=cfg.linear,
+            rope_theta=cfg.rope_theta, positions=positions,
+            flash=cfg.flash_attn), None
     return attn_lib.attention(
         p, x, n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, head_dim=cfg.hd,
         lin_cfg=cfg.linear,
@@ -103,7 +133,8 @@ def apply_block(
     positions=None,
     prefill: bool = False,
 ):
-    """Returns (x, new_cache, aux).
+    """Returns (x, new_cache, aux, stats): ``stats`` holds the counters of
+    :func:`block_stats` (none but for a chip's-share expert layer).
 
     ``cache`` selects the cached (serving) path; ``prefill=True`` marks a
     multi-token teacher-forced pass THROUGH the cache (single-pass prefill) —
@@ -112,6 +143,7 @@ def apply_block(
     """
     new_cache = {} if cache is not None else None
     aux = jnp.zeros((), jnp.float32)
+    stats = {}
     causal = kind != "enc"
 
     x = shard_ctx.constrain_residual(x)
@@ -143,7 +175,7 @@ def apply_block(
                 params["ssm"], h, cfg.linear, d_state=cfg.ssm_state,
                 head_dim=cfg.ssm_head_dim, n_groups=cfg.ssm_groups,
                 chunk=cfg.ssd_chunk)
-        return x + s, new_cache, aux
+        return x + s, new_cache, aux, stats
     else:
         # each sublayer's scope also holds its residual add, so that a
         # fusion XLA roots at that add is named for the sublayer
@@ -173,14 +205,37 @@ def apply_block(
 
     h = _apply_norm(cfg, params["norm2"], x)
     with jax.named_scope("ff"):
-        if kind == "moe":
+        if kind == "moe" and cfg.n_experts_held:
+            m, stats = moe_lib.apply_moe_share(
+                params["moe"], h, cfg.linear, top_k=cfg.top_k,
+                first=cfg.expert_first, score=cfg.router_score,
+                norm_topk=cfg.norm_topk, routed_scale=cfg.routed_scale,
+                chunk=cfg.moe_chunk)
+        elif kind == "moe":
             m, aux = moe_lib.apply_moe(
                 params["moe"], h, cfg.linear, n_experts=cfg.n_experts,
                 top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
                 act=cfg.act, chunk=cfg.moe_chunk)
         else:
             m = mlp_lib.apply_mlp(params["mlp"], h, cfg.linear, act=cfg.act)
-        return x + m, new_cache, aux
+        return x + m, new_cache, aux, stats
+
+
+def block_stats(cfg: ModelCfg, kind: str) -> dict:
+    """Zeroed counters that :func:`apply_block` returns for ``kind``; over
+    the layers each is summed or, named ``max_*``, its largest kept."""
+    if kind == "moe" and cfg.n_experts_held:
+        return {"routed_rows": jnp.zeros((), jnp.float32),
+                "max_expert_rows": jnp.zeros((), jnp.float32)}
+    return {}
+
+
+def merge_stats(a: dict, b: dict) -> dict:
+    out = {**a, **b}
+    for k in a.keys() & b.keys():
+        out[k] = (jnp.maximum(a[k], b[k]) if k.startswith("max_")
+                  else a[k] + b[k])
+    return out
 
 
 def _cross_from_cache(p, cfg: ModelCfg, q_in, cache):

@@ -27,6 +27,15 @@ class ModelCfg:
     rope_theta: Optional[float] = 10000.0
     qk_norm: bool = False
     qkv_bias: bool = False
+    # latent attention (MLA, on where kv_lora_rank > 0): q of
+    # qk_nope + qk_rope per head straight from x (q_lora_rank None: no q
+    # compression), a kv_lora_rank latent plus one shared rope key, values
+    # of v_head_dim per head
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     window: Optional[int] = None          # sliding-window attention
     attn_chunk: Optional[int] = None      # online-softmax key chunking
     # route sdpa through the Pallas flash kernels (prefill grid + ring-cache
@@ -39,6 +48,7 @@ class ModelCfg:
     mlp_bias: bool = False
     # norm / embeddings
     norm: str = "rmsnorm"                 # "rmsnorm" | "layernorm"
+    norm_eps: Optional[float] = None      # None: rmsnorm 1e-6, layernorm 1e-5
     pos_embed: str = "rope"               # "rope" | "learned" | "none"
     max_position: int = 1 << 20
     tie_embeddings: bool = False
@@ -53,8 +63,23 @@ class ModelCfg:
     n_shared: int = 0
     expert_d_ff: int = 0
     capacity_factor: float = 1.25
+    # tokens at a time through an expert layer: the GShard layer's sequence
+    # chunks, the share layer's chunks of its sorted-row buffer
     moe_chunk: Optional[int] = None
     router_aux_coef: float = 0.01
+    # a moe stack's leading dense layers (their ff is d_ff wide)
+    n_dense_layers: int = 0
+    # router: scores "softmax" | "sigmoid" (with a per-expert selection
+    # bias that picks the top_k and does not weigh them), the chosen
+    # scores normalised to sum 1 (norm_topk) and scaled by routed_scale
+    router_score: str = "softmax"
+    norm_topk: bool = True
+    routed_scale: float = 1.0
+    # the experts this chip holds, ids expert_first .. + n_experts_held:
+    # the dropless expert layer of one chip's share (routing over all
+    # n_experts).  0 holds all e_pad experts in the GShard capacity layer.
+    expert_first: int = 0
+    n_experts_held: int = 0
     # ssm (mamba2 / hybrid)
     ssm_state: int = 0
     ssm_head_dim: int = 64
@@ -86,6 +111,13 @@ class ModelCfg:
     def __post_init__(self):
         if self.family not in FAMILIES:
             raise ValueError(f"unknown family {self.family!r}")
+        if self.q_lora_rank:
+            raise ValueError("latent attention with q compression "
+                             "(q_lora_rank) is not implemented")
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @property
     def pdtype(self):

@@ -1,7 +1,9 @@
 """Family-dispatched model: init / forward / loss / cache / decode_step.
 
-Layer params are stacked on a leading ``n_layers`` axis and the stack is
-``jax.lax.scan``-ed (with optional remat) — HLO size stays O(1) in depth.
+Layer params are stacked on a leading layer axis and each group of like
+layers (:func:`layer_groups`: one group, or a moe stack's leading dense
+layers and then its expert layers) is ``jax.lax.scan``-ed (with optional
+remat) — HLO size stays O(1) in depth.
 
 Each layer's ops sit under one ``jax.named_scope`` — ``embed``, ``norm``,
 ``attn``, ``ff``, ``head`` (here and in ``blocks``) and ``optimizer``
@@ -37,6 +39,18 @@ def block_kind(cfg: ModelCfg) -> str:
     return _KIND[cfg.family]
 
 
+def layer_groups(cfg: ModelCfg) -> tuple:
+    """``(params key, block kind, layers)`` of each scanned group of like
+    layers, in stack order: ``layers`` alone, or a moe stack's
+    ``n_dense_layers`` leading dense layers (``dense_layers``, kind ``lm``)
+    and then its expert layers."""
+    if cfg.n_dense_layers:
+        return (("dense_layers", "lm", cfg.n_dense_layers),
+                ("layers", block_kind(cfg),
+                 cfg.n_layers - cfg.n_dense_layers))
+    return (("layers", block_kind(cfg), cfg.n_layers),)
+
+
 def _stacked_init(key, n: int, init_one):
     return jax.vmap(init_one)(jax.random.split(key, n))
 
@@ -47,13 +61,13 @@ def init_params(cfg: ModelCfg, key) -> dict:
     p = {
         "embed": embed_lib.init_embedding(ks[0], cfg.vocab_size, cfg.d_model,
                                           dtype),
-        "layers": _stacked_init(
-            ks[1], cfg.n_layers, lambda k: blocks.init_block(k, cfg,
-                                                             block_kind(cfg))),
         "final_norm": (norms.init_layernorm(cfg.d_model, dtype)
                        if cfg.norm == "layernorm"
                        else norms.init_rmsnorm(cfg.d_model, dtype)),
     }
+    for (key, kind, n), k in zip(layer_groups(cfg), (ks[1], ks[6])):
+        p[key] = _stacked_init(
+            k, n, lambda k, kind=kind: blocks.init_block(k, cfg, kind))
     if not cfg.tie_embeddings:
         p["head"] = embed_lib.init_embedding(ks[2], cfg.vocab_size,
                                              cfg.d_model, dtype)
@@ -74,9 +88,7 @@ def init_params(cfg: ModelCfg, key) -> dict:
 
 def _final_norm(cfg, p, x):
     with jax.named_scope("norm"):
-        if cfg.norm == "layernorm":
-            return norms.layernorm(p, x)
-        return norms.rmsnorm(p, x)
+        return blocks.norm(cfg, p, x)
 
 
 def _unembed(params, x):
@@ -86,33 +98,53 @@ def _unembed(params, x):
 
 def _run_stack(cfg: ModelCfg, stacked, x, kind: str, *, enc_out=None,
                positions=None, caches=None, prefill: bool = False):
-    """scan over stacked layer params (and caches).  Returns (x, caches, aux)."""
+    """scan over stacked layer params (and caches).  Returns (x, caches,
+    aux, stats)."""
 
     def body(carry, scanned):
-        h, aux = carry
+        h, aux, stats = carry
         lp = scanned[0] if caches is not None else scanned
         lc = scanned[1] if caches is not None else None
-        h, nc, a = blocks.apply_block(lp, h, cfg, kind, cache=lc,
-                                      enc_out=enc_out, positions=positions,
-                                      prefill=prefill)
-        return (h, aux + a), nc
+        h, nc, a, st = blocks.apply_block(lp, h, cfg, kind, cache=lc,
+                                          enc_out=enc_out,
+                                          positions=positions,
+                                          prefill=prefill)
+        return (h, aux + a, blocks.merge_stats(stats, st)), nc
 
     if cfg.remat and caches is None:
         body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.nothing_saveable)
 
     xs = (stacked, caches) if caches is not None else stacked
-    (x, aux), new_caches = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                        xs)
-    return x, new_caches, aux
+    init = (x, jnp.zeros((), jnp.float32), blocks.block_stats(cfg, kind))
+    (x, aux, stats), new_caches = jax.lax.scan(body, init, xs)
+    return x, new_caches, aux, stats
+
+
+def _run_layers(cfg: ModelCfg, params, x, *, enc_out=None, positions=None):
+    """Every group of :func:`layer_groups` in turn, no cache.  Returns (x,
+    aux, stats)."""
+    aux, stats = None, {}
+    for key, kind, _ in layer_groups(cfg):
+        x, _, a, st = _run_stack(cfg, params[key], x, kind, enc_out=enc_out,
+                                 positions=positions)
+        aux = a if aux is None else aux + a
+        stats = blocks.merge_stats(stats, st)
+    return x, aux, stats
+
+
+def _one_group(cfg: ModelCfg) -> None:
+    if len(layer_groups(cfg)) > 1:
+        raise NotImplementedError("the cached (serving) paths run one group "
+                                  "of like layers")
 
 
 def encode(cfg: ModelCfg, params, frames):
     """Encoder pass (encdec family).  frames: (B, n_frames, frontend_dim)."""
     x = frontend_lib.apply_frontend(params["frontend"], frames)
     x = x.astype(cfg.cdtype)
-    x, _, _ = _run_stack(cfg, params["enc_layers"], x, "enc",
-                         positions=jnp.arange(x.shape[1]))
+    x, _, _, _ = _run_stack(cfg, params["enc_layers"], x, "enc",
+                            positions=jnp.arange(x.shape[1]))
     return _final_norm(cfg, params["enc_norm"], x)
 
 
@@ -138,29 +170,37 @@ def _embed_inputs(cfg: ModelCfg, params, batch, offset=0):
         return x.astype(cfg.cdtype), positions
 
 
+def _forward(cfg: ModelCfg, params, batch, last_only: bool):
+    """(logits_f32, aux, stats): :func:`forward` with the layers'
+    counters."""
+    x, positions = _embed_inputs(cfg, params, batch)
+    enc_out = None
+    if cfg.family == "encdec":
+        enc_out = encode(cfg, params, batch["frames"])
+    x, aux, stats = _run_layers(cfg, params, x, enc_out=enc_out,
+                                positions=positions)
+    x = _final_norm(cfg, params["final_norm"], x)
+    if cfg.family == "vlm" and "patches" in batch:
+        x = x[:, batch["patches"].shape[1]:]     # logits over text positions
+    if last_only:
+        x = x[:, -1:]
+    return _unembed(params, x), aux, stats
+
+
 def forward(cfg: ModelCfg, params, batch, *, last_only: bool = False):
     """Full-sequence forward.  Returns (logits_f32, aux).
 
     ``last_only`` slices to the final position BEFORE the unembedding —
     the production prefill path (a full (B,S,V) fp32 logit tensor at 32k
     sequence x 150k vocab is tens of GB per device)."""
-    x, positions = _embed_inputs(cfg, params, batch)
-    enc_out = None
-    if cfg.family == "encdec":
-        enc_out = encode(cfg, params, batch["frames"])
-    x, _, aux = _run_stack(cfg, params["layers"], x, block_kind(cfg),
-                           enc_out=enc_out, positions=positions)
-    x = _final_norm(cfg, params["final_norm"], x)
-    if cfg.family == "vlm" and "patches" in batch:
-        x = x[:, batch["patches"].shape[1]:]     # logits over text positions
-    if last_only:
-        x = x[:, -1:]
-    return _unembed(params, x), aux
+    logits, aux, _ = _forward(cfg, params, batch, last_only)
+    return logits, aux
 
 
 def loss_fn(cfg: ModelCfg, params, batch):
-    """Next-token cross-entropy (+ router aux).  labels < 0 are masked."""
-    logits, aux = forward(cfg, params, batch)
+    """Next-token cross-entropy (+ router aux).  labels < 0 are masked.
+    The metrics carry the layers' counters (:func:`blocks.block_stats`)."""
+    logits, aux, stats = _forward(cfg, params, batch, False)
     with jax.named_scope("head"):
         labels = batch["labels"]
         mask = (labels >= 0).astype(jnp.float32)
@@ -176,7 +216,8 @@ def loss_fn(cfg: ModelCfg, params, batch):
         loss = (nll * mask).sum() / denom
         total = loss + cfg.router_aux_coef * aux
         return total, {"loss": loss, "aux": aux,
-                       "ppl_proxy": jnp.exp(jnp.minimum(loss, 20.0))}
+                       "ppl_proxy": jnp.exp(jnp.minimum(loss, 20.0)),
+                       **stats}
 
 
 def init_cache(cfg: ModelCfg, batch: int, max_len: int, dtype=jnp.bfloat16,
@@ -241,9 +282,10 @@ def prefill(cfg: ModelCfg, params, cache, tokens, *, frames=None,
     offset = _cache_pos(cfg, cache)
     x, positions = _embed_inputs(cfg, params, {"tokens": tokens},
                                  offset=offset)
-    x, new_cache, _ = _run_stack(cfg, params["layers"], x, block_kind(cfg),
-                                 positions=positions, caches=cache,
-                                 prefill=True)
+    _one_group(cfg)
+    x, new_cache, _, _ = _run_stack(cfg, params["layers"], x,
+                                    block_kind(cfg), positions=positions,
+                                    caches=cache, prefill=True)
     x = _final_norm(cfg, params["final_norm"], x)
     if last_only:
         x = x[:, -1:]
@@ -254,8 +296,10 @@ def decode_step(cfg: ModelCfg, params, cache, tokens):
     """One-token decode.  tokens: (B, 1).  Returns (logits, new_cache)."""
     offset = _cache_pos(cfg, cache)
     x, positions = _embed_inputs(cfg, params, {"tokens": tokens}, offset=offset)
-    x, new_cache, _ = _run_stack(cfg, params["layers"], x, block_kind(cfg),
-                                 positions=positions, caches=cache)
+    _one_group(cfg)
+    x, new_cache, _, _ = _run_stack(cfg, params["layers"], x,
+                                    block_kind(cfg), positions=positions,
+                                    caches=cache)
     x = _final_norm(cfg, params["final_norm"], x)
     return _unembed(params, x), new_cache
 

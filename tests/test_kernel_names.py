@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels import dyad_mm, flash_attn
+from repro.kernels import dyad_gmm, dyad_mm, flash_attn
 
 F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
 
@@ -51,7 +51,16 @@ Q, KV = ((AB, S, K, G, H), BF16), ((AB, S, K, H), BF16)
 P, NB = 16, 8
 POOL = ((1 + AB * NB, P, K, H), BF16)
 
+# grouped expert rows: 4 experts' DYAD blocks over 256 sorted rows
+GE, GM = 4, 256
+GX, GZ = ((N, GM, DI), BF16), ((N, GM, DO), BF16)
+GW = ((GE, N, DO, DI), BF16)
+STARTS, ENDS = ((GE + 1,), I32), ((GE,), I32)
+
 CASES = {
+    "dyad_gmm": (dyad_gmm.gmm, [GX, GX, GW, GW, STARTS, ENDS]),
+    "dyad_gmm_dgrad": (dyad_gmm.gmm_dgrad, [GZ, GW, GW, STARTS, ENDS]),
+    "dyad_gmm_wgrad": (dyad_gmm.gmm_wgrad, [GX, GX, GZ, STARTS, ENDS]),
     "dyad_mm": (dyad_mm.dyad_mm_blocks, [X, X, W, W]),
     "dyad_mm_dgrad": (dyad_mm.dyad_mm_dgrad, [Z, Z, W, W]),
     "dyad_wgrad": (dyad_mm.dyad_mm_wgrad, [X, X, Z, Z]),
@@ -79,6 +88,9 @@ CASES = {
 
 # entry point -> the (wrapper, name=) of each kernel it runs
 WANT = {
+    "dyad_gmm": [("_gmm_impl", None)],
+    "dyad_gmm_dgrad": [("_gmm_impl", None)],
+    "dyad_gmm_wgrad": [("_gmm_wgrad_impl", None)],
     "dyad_mm": [("_mm_impl", None)],
     "dyad_mm_dgrad": [("_mm_impl", None)],
     "dyad_wgrad": [("_wgrad_impl", None)],
@@ -97,3 +109,22 @@ def test_kernel_name_in_jaxpr(entry):
     fn, shapes = CASES[entry]
     jaxpr = jax.make_jaxpr(fn)(*_sds(*shapes)).jaxpr
     assert sorted(_kernels(jaxpr), key=str) == sorted(WANT[entry], key=str)
+
+
+def _reader(name):
+    from chipbench import common
+    return common.load_module(f"{common.BENCH}/metrics/{name}.py")
+
+
+@pytest.mark.parametrize("entry", ["dyad_gmm", "dyad_gmm_dgrad",
+                                   "dyad_gmm_wgrad"])
+def test_grouped_kernels_escape_the_other_readers(entry):
+    """A trace event of a grouped kernel (``%_gmm_impl.3 = ...``) is counted
+    by the grouped reader only, not by the DYAD (``%_mm_impl``,
+    ``%_wgrad_impl``) or the flash (``%_prefill_impl``, ``%_dq_impl``,
+    ``%_dkv_impl``) reader."""
+    for wrapper, _ in WANT[entry]:
+        event = f"%{wrapper}.3 = bf16[2,256,8]{{2,1,0}} custom-call(...)"
+        assert not _reader("dyad_mm_roofline").match(event)
+        assert not _reader("flash_attn_roofline").match(event)
+        assert _reader("expert_gmm_roofline").match(event)

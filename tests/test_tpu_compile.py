@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import dyad_mm, flash_attn
+from repro.kernels import dyad_gmm, dyad_mm, flash_attn
 
 F32, BF16 = jnp.float32, jnp.bfloat16
 
@@ -137,24 +137,51 @@ OPT_ATTN = (8, 512, 12, 1, 64, F32)
 QWEN_ATTN = (1, 512, 8, 2, 128, BF16)
 
 
-@pytest.mark.parametrize("width", [OPT_ATTN, QWEN_ATTN])
+# Moonlight-16B-A3B's latent attention: 16 heads of qk 192 against v 128,
+# bf16, one 8,192-token sequence (the value width rides last)
+MLA_ATTN = (1, 8192, 16, 1, 192, BF16, 128)
+
+
+@pytest.mark.parametrize("width", [OPT_ATTN, QWEN_ATTN, MLA_ATTN])
 def test_flash_prefill_with_lse_compiles(one_chip, width):
-    B, S, K, G, h, dt = width
+    B, S, K, G, h, dt = width[:6]
+    dv = width[6] if len(width) > 6 else h
     fn = lambda q, k, v: flash_attn.flash_prefill(q, k, v, save_lse=True)
     text = _compile_text(fn, one_chip, ((B, S, K, G, h), dt),
-                         ((B, S, K, h), dt), ((B, S, K, h), dt))
+                         ((B, S, K, h), dt), ((B, S, K, dv), dt))
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("width", [OPT_ATTN, QWEN_ATTN])
+@pytest.mark.parametrize("width", [OPT_ATTN, QWEN_ATTN, MLA_ATTN])
 def test_flash_prefill_grads_compiles(one_chip, width):
-    B, S, K, G, h, dt = width
-    q, kv = ((B, S, K, G, h), dt), ((B, S, K, h), dt)
+    B, S, K, G, h, dt = width[:6]
+    dv = width[6] if len(width) > 6 else h
+    q, k = ((B, S, K, G, h), dt), ((B, S, K, h), dt)
+    v, o = ((B, S, K, dv), dt), ((B, S, K, G, dv), dt)
     fn = lambda q, k, v, o, lse, do: flash_attn.flash_prefill_grads(
         q, k, v, o, lse, do)
-    text = _compile_text(fn, one_chip, q, kv, kv, q,
-                         ((B, K, S * G), F32), q)
+    text = _compile_text(fn, one_chip, q, k, v, o, ((B, K, S * G), F32), o)
     assert "tpu_custom_call" in text
+
+
+# Moonlight's routed experts: 8 held, DYAD n=4 blocks of d_model/n = 512
+# and expert d_ff/n = 352, over one 4,096-token chunk's worst-case buffer
+GMM_E, GMM_N, GMM_D, GMM_F = 8, 4, 512, 352
+GMM_M = dyad_gmm.buffer_rows(4096 * 6, GMM_E)
+
+
+@pytest.mark.parametrize("op", ["fwd", "dgrad", "wgrad"])
+@pytest.mark.parametrize("proj", ["up", "down"])
+def test_dyad_gmm_compiles(one_chip, op, proj):
+    d_in, d_out = (GMM_D, GMM_F) if proj == "up" else (GMM_F, GMM_D)
+    x, z = ((GMM_N, GMM_M, d_in), BF16), ((GMM_N, GMM_M, d_out), BF16)
+    w = ((GMM_E, GMM_N, d_out, d_in), BF16)
+    groups = [((GMM_E + 1,), jnp.int32), ((GMM_E,), jnp.int32)]
+    fn, shapes = {"fwd": (dyad_gmm.gmm, [x, x, w, w]),
+                  "dgrad": (dyad_gmm.gmm_dgrad, [z, w, w]),
+                  "wgrad": (dyad_gmm.gmm_wgrad, [x, x, z])}[op]
+    assert "tpu_custom_call" in _compile_text(fn, one_chip,
+                                              *shapes, *groups)
 
 
 def test_flash_decode_compiles(one_chip):
